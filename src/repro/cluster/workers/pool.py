@@ -26,6 +26,8 @@ from __future__ import annotations
 import threading
 import time
 
+import jax
+
 from repro.core.engine import KeywordSearchEngine
 
 from ..partition import ShardSpec
@@ -100,7 +102,11 @@ class WorkerPool:
 
 
 class ThreadPool(WorkerPool):
-    """In-process engines behind QueryService drain threads (PR 2)."""
+    """In-process engines behind QueryService drain threads.
+
+    Shard ``i`` runs on ``jax.devices()[i % len(jax.devices())]``, so one
+    process spreads a sharded cluster over a host's chips.
+    """
 
     transport = "thread"
 
@@ -117,29 +123,29 @@ class ThreadPool(WorkerPool):
         self._backends = backends
         self._max_batch = max_batch
         self._batch_window_ms = batch_window_ms
+        self._devices = jax.devices()
         self.workers = [
-            ThreadWorker(
-                spec,
-                engine,
-                backend=be,
-                max_batch=max_batch,
-                batch_window_ms=batch_window_ms,
-            )
-            for (spec, engine), be in zip(shards, backends)
+            self._worker(spec, engine, i)
+            for i, (spec, engine) in enumerate(shards)
         ]
 
-    def spawn(self, i: int, path: str | None = None) -> ThreadWorker:
-        if path is None:
-            raise ValueError("thread transport reloads need an artifact path")
-        old = self.workers[i]
-        engine = KeywordSearchEngine.load(path, mmap=True)
+    def _worker(
+        self, spec: ShardSpec, engine: KeywordSearchEngine, i: int
+    ) -> ThreadWorker:
         return ThreadWorker(
-            old.spec,
+            spec,
             engine,
             backend=self._backends[i],
             max_batch=self._max_batch,
             batch_window_ms=self._batch_window_ms,
+            device=self._devices[i % len(self._devices)],
         )
+
+    def spawn(self, i: int, path: str | None = None) -> ThreadWorker:
+        if path is None:
+            raise ValueError("thread transport reloads need an artifact path")
+        engine = KeywordSearchEngine.load(path, mmap=True)
+        return self._worker(self.workers[i].spec, engine, i)
 
     def rebuild(
         self, entries: list[tuple[ShardSpec, str]], manifest: dict

@@ -16,15 +16,19 @@ it synchronously, and the ``on_death`` callback lets the
 """
 from __future__ import annotations
 
+import collections
 import os
 import subprocess
 import sys
+import threading
 
 import repro
 
 from ..partition import ShardSpec
 from .base import DEFAULT_OP_TIMEOUT, RpcWorker
 from .proto import write_frame
+
+STDERR_TAIL_LINES = 20  # child stderr lines kept for the WorkerDied detail
 
 
 def _pythonpath_for_child() -> str:
@@ -75,11 +79,30 @@ class ProcessWorker(RpcWorker):
             ],
             stdin=subprocess.PIPE,
             stdout=subprocess.PIPE,
-            env=env,  # stderr inherited: worker tracebacks stay visible
+            stderr=subprocess.PIPE,
+            env=env,
         )
         self._rfile = self._proc.stdout
         self._wfile = self._proc.stdin
+        # the child's stderr is forwarded to ours (tracebacks stay visible)
+        # and its tail kept for the WorkerDied detail
+        self._stderr_tail: collections.deque[bytes] = collections.deque(
+            maxlen=STDERR_TAIL_LINES
+        )
+        self._stderr_pump = threading.Thread(
+            target=self._pump_stderr, name=f"shard{spec.index}-stderr",
+            daemon=True,
+        )
+        self._stderr_pump.start()
         self._start_reader(f"shard{spec.index}-rpc-reader")
+
+    def _pump_stderr(self) -> None:
+        for line in self._proc.stderr:
+            self._stderr_tail.append(line)
+            try:
+                os.write(2, line)
+            except OSError:
+                pass
 
     # ------------------------------------------------------------------ #
     # Worker protocol (the RPC ops live on RpcWorker)
@@ -115,4 +138,10 @@ class ProcessWorker(RpcWorker):
         self._reader.join(5.0)
 
     def _death_detail(self, detail: str) -> str:
-        return f"{detail} (exit code {self._proc.poll()})"
+        # the child's last words arrive on stderr around its EOF: give the
+        # pump a moment to drain them (bounded: a wedged child cannot hang
+        # the death path)
+        self._stderr_pump.join(5.0)
+        tail = b"".join(self._stderr_tail).decode(errors="replace").rstrip()
+        out = f"{detail} (exit code {self._proc.poll()})"
+        return f"{out}; stderr tail:\n{tail}" if tail else out

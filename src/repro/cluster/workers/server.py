@@ -45,6 +45,7 @@ from concurrent.futures import ThreadPoolExecutor
 from typing import BinaryIO
 
 from repro.core.engine import KeywordSearchEngine
+from repro.device import enable_compile_cache, refuse_cpu_fallback
 from repro.obs import TRACER
 from repro.serve.service import QueryService
 
@@ -307,6 +308,10 @@ def main(argv: list[str] | None = None) -> int:
 
     if args.no_trace:
         TRACER.enabled = False
+    enable_compile_cache()
+    # open the accelerator first: a server that cannot have the chip
+    # (another process holds it) fails at start-up, before loading
+    refuse_cpu_fallback()
     state = EngineState(
         args.dir,
         backend=args.backend,

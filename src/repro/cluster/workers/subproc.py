@@ -47,6 +47,7 @@ def main(argv: list[str] | None = None) -> int:
     os.dup2(sys.stderr.fileno(), sys.stdout.fileno())
     rpc_in = os.fdopen(os.dup(sys.stdin.fileno()), "rb")
 
+    from repro.device import enable_compile_cache, refuse_cpu_fallback
     from repro.obs import TRACER
 
     from .proto import write_frame
@@ -54,6 +55,10 @@ def main(argv: list[str] | None = None) -> int:
 
     if args.no_trace:
         TRACER.enabled = False
+    enable_compile_cache()
+    # open the accelerator first: a child that cannot have the chip
+    # (another process holds it) dies before it is ready, not on a query
+    refuse_cpu_fallback()
     state = EngineState(
         args.dir,
         backend=args.backend,

@@ -3,6 +3,8 @@
 One :class:`~repro.serve.service.QueryService` drain thread per shard,
 exactly what ``router.py`` used to build inline — extracted here so the
 router sees only the :class:`~repro.cluster.workers.base.Worker` seam.
+``device`` pins the shard's launches to one JAX device: one process drives
+every chip of a host, a shard per chip.
 """
 from __future__ import annotations
 
@@ -29,6 +31,7 @@ class ThreadWorker:
         backend: str = "jax",
         max_batch: int = 64,
         batch_window_ms: float = 2.0,
+        device=None,
     ):
         self.spec = spec
         self.engine = engine
@@ -37,6 +40,7 @@ class ThreadWorker:
             max_batch=max_batch,
             batch_window_ms=batch_window_ms,
             backend=backend,
+            device=device,
         )
         # local ids of this shard's document roots (children of the replica
         # root), ascending — the probe set for doc_stats

@@ -175,6 +175,18 @@ class IDClusterIndex:
     def rcpm_lookup(self, node_id: int) -> RCPMEntry | None:
         return self.rcs.rcpm_lookup(node_id)
 
+    def nested_rcs(self, rc: int, ids: np.ndarray) -> list[int]:
+        """RCs that the dummies among ``rc``'s result ``ids`` point to, once
+        each in first-seen order: one vectorized RCPM probe for the whole
+        result set (a per-id probe costs a host call per result)."""
+        dummy_ids = self.rcs.dummy_ids
+        if not dummy_ids.size or not ids.size:
+            return []
+        pos = np.clip(np.searchsorted(dummy_ids, ids), 0, dummy_ids.size - 1)
+        hit = (dummy_ids[pos] == ids) & (ids != self.rc_root_id(rc))
+        nested = self.rcs.dummy_nested_rc[pos[hit]].tolist()
+        return list(dict.fromkeys(nested))
+
     def idlist(self, rc: int, kw: int) -> IDList:
         """IDList of keyword ``kw`` inside redundancy component ``rc``."""
         key = (rc, kw)

@@ -16,14 +16,19 @@ maps to an empty result, so R-padding is free of special cases.
 
 The cache is engine-owned (each :class:`KeywordSearchEngine` carries one) but
 can be shared across engines serving the same process; hit/miss/launch
-counters feed ``QueryStats`` and the service benchmarks.
+counters feed ``QueryStats`` and the service benchmarks.  ``device`` pins
+every launch to one JAX device (a shard per chip); None leaves placement
+to JAX's default device.
 """
 from __future__ import annotations
 
 import time
 from dataclasses import dataclass, field
 
+import jax
 import numpy as np
+
+from repro.roofline.analysis import peaks, search_pipeline_bytes
 
 from .idlist import IDList
 from .search_vec import INT_PAD, bucket, ca_search_batch
@@ -46,9 +51,10 @@ class PlanKey:
 class PlanCache:
     """Packs work items to bucketed shapes and tracks executable reuse."""
 
-    def __init__(self, backend: str = "xla", min_rows: int = 1):
+    def __init__(self, backend: str = "xla", min_rows: int = 1, device=None):
         self.backend = backend
         self.min_rows = min_rows
+        self.device = device
         self.launches = 0  # device calls issued
         self.hits = 0  # launches whose shape signature was seen before
         self.misses = 0  # launches that compiled a new executable
@@ -88,6 +94,20 @@ class PlanCache:
         callers must treat that as "unknown", not "zero"."""
         cache_size = getattr(ca_search_batch, "_cache_size", None)
         return int(cache_size()) if cache_size is not None else -1
+
+    def _xla_batch(self, batch: dict, semantics: str, backend: str):
+        """The jitted batch search, on this cache's device when pinned."""
+        if self.device is not None:
+            batch = jax.device_put(batch, self.device)
+        return ca_search_batch(**batch, semantics=semantics, backend=backend)
+
+    def _hbm_bw(self) -> float | None:
+        """HBM bandwidth of the launch device: None on the CPU (no chip to
+        bound against); an accelerator without published peaks raises."""
+        device = self.device or jax.devices()[0]
+        if device.platform == "cpu":
+            return None
+        return peaks(device.device_kind)["hbm_bw"]
 
     # ------------------------------------------------------------------ #
     def pack(
@@ -206,19 +226,16 @@ class PlanCache:
                 # giant shortest list: the fused variant would blow VMEM —
                 # demote this launch to the chained batch path
                 self.fused_fallbacks += 1
-                ids, mask = ca_search_batch(
-                    **batch, semantics=semantics, backend="xla"
-                )
+                ids, mask = self._xla_batch(batch, semantics, "xla")
                 kstats = {"fallback": True}
             else:
                 kstats = {}
                 ids, mask = fused_search_batch(
-                    **batch, semantics=semantics, stats=kstats
+                    **batch, semantics=semantics, stats=kstats,
+                    device=self.device,
                 )
         else:
-            ids, mask = ca_search_batch(
-                **batch, semantics=semantics, backend=backend
-            )
+            ids, mask = self._xla_batch(batch, semantics, backend)
             kstats = None
         ids = np.asarray(ids)
         mask = np.asarray(mask)
@@ -232,16 +249,12 @@ class PlanCache:
                     "rows": sig.rows,
                 }
                 attrs.update(kstats)
-                try:
-                    from repro.roofline.analysis import search_pipeline_bytes
-
-                    attrs.update(search_pipeline_bytes(
-                        rows=sig.rows, k=sig.k, m0=sig.m0, mo=sig.mo,
-                        window=kstats.get("window", 1),
-                        bo=kstats.get("bo", 512),
-                    ).attrs())
-                except Exception:  # roofline is advisory, never hot-path fatal
-                    pass
+                attrs.update(search_pipeline_bytes(
+                    rows=sig.rows, k=sig.k, m0=sig.m0, mo=sig.mo,
+                    window=kstats.get("window", 1),
+                    bo=kstats.get("bo", 512),
+                    hbm_bw=self._hbm_bw(),
+                ).attrs())
                 phases.append({
                     "name": "kernel.fused_round",
                     "t0_ms": w1, "dur_ms": (time.perf_counter() - p1) * 1e3,

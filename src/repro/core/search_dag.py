@@ -83,13 +83,9 @@ def dag_search_vec(
         for rc in frontier:
             res = results[rc]
             memo[rc] = res
-            root = index.rc_root_id(rc)
-            for x in res:
-                if x == root:
-                    continue
-                e = index.rcpm_lookup(int(x))
-                if e is not None and e.rc not in memo and e.rc not in nxt:
-                    nxt.append(e.rc)
+            for nested in index.nested_rcs(rc, res):
+                if nested not in memo and nested not in nxt:
+                    nxt.append(nested)
         frontier = nxt
     if stats is not None:
         stats["rounds"] = rounds
@@ -149,18 +145,14 @@ def dag_search_vec_multi(
             for qi, rc in items:
                 res = results[(qi, rc)]
                 memos[qi][rc] = res
-                root = index.rc_root_id(rc)
-                for x in res:
-                    if x == root:
-                        continue
-                    e = index.rcpm_lookup(int(x))
-                    if e is not None and e.rc not in memos[qi]:
+                for nested in index.nested_rcs(rc, res):
+                    if nested not in memos[qi]:
                         # claim with a placeholder so later items of this (and
                         # the next) round cannot re-enqueue the same RC; the
                         # claim is overwritten with the real result when its
                         # frontier round executes
-                        memos[qi][e.rc] = None
-                        nxt.append((qi, e.rc))
+                        memos[qi][nested] = None
+                        nxt.append((qi, nested))
         frontier = nxt
     if stats is not None:
         stats["rounds"] = rounds

@@ -6,7 +6,8 @@ walking, we
   1. intersect by *membership*: every element of the shortest list L0 is
      binary-searched into the other lists (vectorized `searchsorted`, or the
      Pallas block kernel when backend="pallas");
-  2. compact the CA set with a sort (pad = INT32_MAX sorts to the tail);
+  2. compact the CA set by prefix sum (L0 is ascending, so it stays
+     sorted; pad = INT32_MAX fills the tail);
   3. SLCA: a CA is SLCA iff the *next* CA's parent differs (ancestor-closure
      argument, DESIGN.md §2) — one shift-compare;
   4. ELCA: scatter-add child NDesc onto parent CA positions (`segment_sum`)
@@ -89,33 +90,40 @@ def ca_search(
         ca_mask = valid0
         nd = ndesc0[None, :]
 
-    # compact CA set ascending via one sort (pads go to the tail)
-    ca_ids = jnp.where(ca_mask, ids0, INT_PAD)
-    order = jnp.argsort(ca_ids)
-    ca_sorted = ca_ids[order]
+    # compact the CA set ascending (pads to the tail).  ids0 is ascending, so
+    # a stable compaction -- prefix-sum slots, one scatter -- keeps it sorted
+    # without a sort, whose TPU compile alone takes seconds per bucket
+    slot = jnp.where(ca_mask, jnp.cumsum(ca_mask, dtype=jnp.int32) - 1, m0)
+
+    def compact(v, fill):
+        return jnp.full((m0,), fill, v.dtype).at[slot].set(v, mode="drop")
+
+    ca_sorted = compact(ids0, INT_PAD)
     cnt = jnp.sum(ca_mask).astype(jnp.int32)
     idx = jnp.arange(m0, dtype=jnp.int32)
     valid = idx < cnt
 
-    par_sorted = jnp.where(ca_mask, pid0, -1)[order]
+    par_sorted = compact(pid0, -1)
 
     if semantics == "slca":
         next_par = jnp.concatenate([par_sorted[1:], jnp.full((1,), -1, jnp.int32)])
         is_last = idx == cnt - 1
         res_mask = valid & (is_last | (next_par != ca_sorted))
     elif semantics == "elca":
-        nd_sorted = jnp.take(nd, order, axis=1)  # [k, m0]
         # position of each CA's parent inside the compacted CA array
         pp = jnp.searchsorted(ca_sorted, par_sorted).astype(jnp.int32)
         pp_c = jnp.minimum(pp, m0 - 1)
         par_is_ca = valid & (par_sorted >= 0) & (ca_sorted[pp_c] == par_sorted)
         seg = jnp.where(par_is_ca, pp_c, m0)  # overflow bucket for roots/invalid
-        child_sum = jax.vmap(
-            lambda v: jax.ops.segment_sum(
-                jnp.where(valid, v, 0), seg, num_segments=m0 + 1
+        # one 1-D scatter per keyword (k is static and tiny): the same
+        # scatter vmapped over the keyword axis compiles ~10x slower on TPU
+        res_mask = valid
+        for nd_k in nd:
+            nd_sorted = compact(nd_k, 0)
+            child_sum = jax.ops.segment_sum(
+                jnp.where(valid, nd_sorted, 0), seg, num_segments=m0 + 1
             )[:m0]
-        )(nd_sorted)
-        res_mask = valid & jnp.all(nd_sorted - child_sum >= 1, axis=0)
+            res_mask = res_mask & (nd_sorted - child_sum >= 1)
     elif semantics == "ca":
         res_mask = valid
     else:  # pragma: no cover

@@ -21,6 +21,7 @@ import threading
 
 from repro.cluster.router import ClusterService
 from repro.cluster.workers.base import WorkerDied
+from repro.device import enable_compile_cache
 
 from .http import Gateway
 
@@ -43,6 +44,7 @@ def main(argv: list[str] | None = None) -> int:
     )
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     pool_kw = {}
     if args.hedge_ms is not None and args.transport in ("process", "remote"):
         pool_kw["hedge_ms"] = args.hedge_ms

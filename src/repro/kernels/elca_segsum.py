@@ -17,6 +17,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.device import pallas_interpret
+
 DEFAULT_BI = 512
 DEFAULT_BJ = 512
 
@@ -43,7 +45,7 @@ def elca_segsum_pallas_call(
     *,
     bi: int = DEFAULT_BI,
     bj: int = DEFAULT_BJ,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     mi, mj = ca_padded.shape[0], par_padded.shape[0]
     k = nd_padded.shape[0]
@@ -59,6 +61,6 @@ def elca_segsum_pallas_call(
         ],
         out_specs=pl.BlockSpec((k, bi), lambda i, j: (0, i)),
         out_shape=jax.ShapeDtypeStruct((k, mi), jnp.int32),
-        interpret=interpret,
+        interpret=pallas_interpret(interpret),
     )(ca_padded[None, :], par_padded[None, :], nd_padded)
     return out

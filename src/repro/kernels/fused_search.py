@@ -48,6 +48,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.device import pallas_interpret
+
 from .shapes import INT_PAD, bucket_pow2
 
 DEFAULT_BO = 512  # other-list block (the streamed axis)
@@ -59,16 +61,27 @@ DEFAULT_CI = 512  # L0-axis chunk for the compare tiles
 MAX_FUSED_M0 = 8192
 
 
+def _chunks(n: int, body, init):
+    """``fori_loop`` over the ``n`` L0 chunks; a lone chunk stays static.
+
+    A rolled loop keeps the kernel body (and its compile time) the same size
+    whatever the bucket, where a Python-unrolled one grows with ``m0`` (and
+    quadratically in the finalize pass)."""
+    if n == 1:
+        return body(0, init)
+    return jax.lax.fori_loop(0, n, body, init)
+
+
 def _fused_kernel(
     start_ref,  # scalar prefetch: [R] int32 first other-block per row
     n0_ref,  # scalar prefetch: [R] int32 valid length of L0 per row
-    ids0_ref,  # [1, m0] L0 ids (ascending, INT_PAD tail)
-    pid0_ref,  # [1, m0] L0 parent *ids* (-1 if none)
-    nd0_ref,  # [1, m0] L0 NDesc
+    ids0_ref,  # [1, 1, m0] L0 ids (ascending, INT_PAD tail)
+    pid0_ref,  # [1, 1, m0] L0 parent *ids* (-1 if none)
+    nd0_ref,  # [1, 1, m0] L0 NDesc
     oth_ref,  # [1, k1m, BO] other-list ids tile
     ond_ref,  # [1, k1m, BO] other-list NDesc tile
-    keep_ids_ref,  # out [1, m0]: result ids (INT_PAD at dropped slots)
-    keep_mask_ref,  # out [1, m0] int32: 1 where keep_ids is a result
+    keep_ids_ref,  # out [1, 1, m0]: result ids (INT_PAD at dropped slots)
+    keep_mask_ref,  # out [1, 1, m0] int32: 1 where keep_ids is a result
     found_ref,  # out/acc [1, k1m, m0] int32 membership mask per keyword
     ndo_ref,  # out/acc [1, k1m, m0] int32 gathered other NDesc
     cam_ref,  # scratch [1, m0] int32: CA mask (finalize pass 1)
@@ -85,83 +98,96 @@ def _fused_kernel(
     j = pl.program_id(1)
     nci = m0 // ci
 
+    def chunk(c):
+        # lane window of L0 chunk c: static for a lone chunk, else aligned
+        # (ci is a power of two and a multiple of 128 when m0 > ci)
+        if isinstance(c, int):
+            return pl.ds(c * ci, ci)
+        return pl.ds(pl.multiple_of(c * ci, ci), ci)
+
     @pl.when(j == 0)
     def _init():
         found_ref[...] = jnp.zeros_like(found_ref)
         ndo_ref[...] = jnp.zeros_like(ndo_ref)
 
     if k1:
-        # ---- streamed phase: membership + ndesc gather for this tile ---- #
         # the index map clamps (start + j) to the last block; a clamped
         # revisit must contribute nothing (the ndesc sum is not idempotent)
-        live = start_ref[r] + j < nob
-        for c in range(nci):
-            sl = slice(c * ci, (c + 1) * ci)
-            q = ids0_ref[0, sl]  # [ci]
-            for kk in range(k1):  # k is tiny (1-3): unrolled
-                tile = oth_ref[0, kk, :]  # [BO]
-                ndt = ond_ref[0, kk, :]
-                eq = (q[:, None] == tile[None, :]) & live  # [ci, BO]
-                hit = jnp.any(eq, axis=1).astype(jnp.int32)
-                # ids unique per list => at most one eq per row: the masked
-                # sum IS the gather of the matching entry's NDesc
-                nds = jnp.sum(jnp.where(eq, ndt[None, :], 0), axis=1)
-                found_ref[0, kk, sl] |= hit
-                ndo_ref[0, kk, sl] += nds
+        @pl.when(start_ref[r] + j < nob)
+        def _stream():
+            # streamed phase: membership + ndesc gather for this tile
+            def body(c, carry):
+                sl = chunk(c)
+                q = ids0_ref[0, 0, sl]  # [ci]
+                for kk in range(k1):  # k is tiny (1-3): unrolled
+                    tile = oth_ref[0, kk, :]  # [BO]
+                    ndt = ond_ref[0, kk, :]
+                    eq = q[:, None] == tile[None, :]  # [ci, BO]
+                    hit = jnp.any(eq, axis=1).astype(jnp.int32)
+                    # ids unique per list => at most one eq per row: the
+                    # masked sum IS the gather of the matching entry's NDesc
+                    nds = jnp.sum(jnp.where(eq, ndt[None, :], 0), axis=1)
+                    found_ref[0, kk, sl] |= hit
+                    ndo_ref[0, kk, sl] += nds
+                return carry
+
+            _chunks(nci, body, 0)
 
     @pl.when(j == window - 1)
     def _finalize():
         n0 = n0_ref[r]
-        iota = jax.lax.broadcasted_iota(jnp.int32, (1, m0), 1)[0]
-        valid0 = iota < n0
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, ci), 1)[0]
+
         # pass 1: CA mask (valid & member of every other list)
-        for c in range(nci):
-            sl = slice(c * ci, (c + 1) * ci)
-            ca = valid0[sl]
+        def ca_pass(c, carry):
+            sl = chunk(c)
+            ca = c * ci + lane < n0
             for kk in range(k1):
                 ca = ca & (found_ref[0, kk, sl] != 0)
             cam_ref[0, sl] = ca.astype(jnp.int32)
+            return carry
+
+        _chunks(nci, ca_pass, 0)
+
+        def nd_rows(sl):
+            return [nd0_ref[0, 0, sl]] + [ndo_ref[0, kk, sl] for kk in range(k1)]
+
         # pass 2: parent aggregation over the CA set.  One equality mask
         # per (ci x cj) tile serves the SLCA child count and all K ELCA
         # NDesc rows -- the segsum fusion, inside the same launch.
-        for c in range(nci):
-            sl_i = slice(c * ci, (c + 1) * ci)
-            ids_c = ids0_ref[0, sl_i]
-            cnt = jnp.zeros((ci,), jnp.int32)
-            sums = (
-                [jnp.zeros((ci,), jnp.int32) for _ in range(k1 + 1)]
-                if semantics == "elca"
-                else []
-            )
-            for d in range(nci):
-                sl_d = slice(d * ci, (d + 1) * ci)
-                pid_d = pid0_ref[0, sl_d]
-                cam_d = cam_ref[0, sl_d] != 0
-                eq = (pid_d[None, :] == ids_c[:, None]) & cam_d[None, :]
-                cnt = cnt + jnp.sum(eq.astype(jnp.int32), axis=1)
-                if semantics == "elca":
-                    nd_rows = [nd0_ref[0, sl_d]] + [
-                        ndo_ref[0, kk, sl_d] for kk in range(k1)
-                    ]
-                    for k, row in enumerate(nd_rows):
-                        sums[k] = sums[k] + jnp.sum(
-                            jnp.where(eq, row[None, :], 0), axis=1
-                        )
+        def row_pass(c, carry):
+            sl_i = chunk(c)
+            ids_c = ids0_ref[0, 0, sl_i]
             cam_c = cam_ref[0, sl_i] != 0
-            if semantics == "slca":
-                # ancestor closure: SLCA iff no CA child anywhere
-                keep = cam_c & (cnt == 0)
-            elif semantics == "elca":
-                nd_rows_i = [nd0_ref[0, sl_i]] + [
-                    ndo_ref[0, kk, sl_i] for kk in range(k1)
-                ]
-                keep = cam_c
-                for k, row in enumerate(nd_rows_i):
-                    keep = keep & (row - sums[k] >= 1)
-            else:  # "ca"
-                keep = cam_c
-            keep_mask_ref[0, sl_i] = keep.astype(jnp.int32)
-            keep_ids_ref[0, sl_i] = jnp.where(keep, ids_c, INT_PAD)
+
+            def col_pass(d, acc):
+                sl_d = chunk(d)
+                eq = (pid0_ref[0, 0, sl_d][None, :] == ids_c[:, None]) & (
+                    cam_ref[0, sl_d] != 0
+                )[None, :]
+                if semantics == "slca":
+                    return (acc[0] + jnp.sum(eq.astype(jnp.int32), axis=1),)
+                return tuple(
+                    a + jnp.sum(jnp.where(eq, row[None, :], 0), axis=1)
+                    for a, row in zip(acc, nd_rows(sl_d))
+                )
+
+            keep = cam_c
+            if semantics in ("slca", "elca"):
+                n_acc = 1 if semantics == "slca" else k1 + 1
+                zero = jnp.zeros((ci,), jnp.int32)
+                acc = _chunks(nci, col_pass, (zero,) * n_acc)
+                if semantics == "slca":
+                    # ancestor closure: SLCA iff no CA child anywhere
+                    keep = keep & (acc[0] == 0)
+                else:
+                    for row, sums in zip(nd_rows(sl_i), acc):
+                        keep = keep & (row - sums >= 1)
+            keep_mask_ref[0, 0, sl_i] = keep.astype(jnp.int32)
+            keep_ids_ref[0, 0, sl_i] = jnp.where(keep, ids_c, INT_PAD)
+            return carry
+
+        _chunks(nci, row_pass, 0)
 
 
 @functools.lru_cache(maxsize=None)
@@ -176,56 +202,43 @@ def _fused_variant(
     semantics: str,
     interpret: bool,
 ):
-    """One compiled executable per static shape signature (jit-cached)."""
+    """One compiled executable per static shape signature (jit-cached).
+
+    Row operands are laid out ``[R, 1, m0]`` so each block's last two dims
+    equal the array's (Mosaic's tiling rule); a ``(1, m0)`` block over
+    ``[R, m0]`` compiles only for ``R == 1``.
+    """
     k1m = max(k1, 1)
 
     def row_map(r, j, starts, n0):
-        return (r, 0)
+        return (r, 0, 0)
 
     def tile_map(r, j, starts, n0):
         return (r, 0, jnp.minimum(starts[r] + j, nob - 1))
 
-    def acc_map(r, j, starts, n0):
-        return (r, 0, 0)
-
+    row_spec = pl.BlockSpec((1, 1, m0), row_map)
+    acc_spec = pl.BlockSpec((1, k1m, m0), row_map)
+    tile_spec = pl.BlockSpec((1, k1m, bo), tile_map)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(rows, window),
-        in_specs=[
-            pl.BlockSpec((1, m0), row_map),  # ids0
-            pl.BlockSpec((1, m0), row_map),  # pid0
-            pl.BlockSpec((1, m0), row_map),  # nd0
-            pl.BlockSpec((1, k1m, bo), tile_map),  # other ids tile
-            pl.BlockSpec((1, k1m, bo), tile_map),  # other ndesc tile
-        ],
-        out_specs=[
-            pl.BlockSpec((1, m0), row_map),  # keep_ids
-            pl.BlockSpec((1, m0), row_map),  # keep_mask
-            pl.BlockSpec((1, k1m, m0), acc_map),  # found acc
-            pl.BlockSpec((1, k1m, m0), acc_map),  # ndo acc
-        ],
+        in_specs=[row_spec, row_spec, row_spec, tile_spec, tile_spec],
+        out_specs=[row_spec, row_spec, acc_spec, acc_spec],
         scratch_shapes=[pltpu.VMEM((1, m0), jnp.int32)],
     )
     kernel = functools.partial(
         _fused_kernel, k1=k1, m0=m0, bo=bo, nob=nob, window=window, ci=ci,
         semantics=semantics,
     )
+    row_out = jax.ShapeDtypeStruct((rows, 1, m0), jnp.int32)
+    acc_out = jax.ShapeDtypeStruct((rows, k1m, m0), jnp.int32)
     call = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((rows, m0), jnp.int32),
-            jax.ShapeDtypeStruct((rows, m0), jnp.int32),
-            jax.ShapeDtypeStruct((rows, k1m, m0), jnp.int32),
-            jax.ShapeDtypeStruct((rows, k1m, m0), jnp.int32),
-        ],
+        out_shape=[row_out, row_out, acc_out, acc_out],
         interpret=interpret,
     )
-    return jax.jit(
-        lambda starts, n0, ids0, pid0, nd0, oth, ond: call(
-            starts, n0, ids0, pid0, nd0, oth, ond
-        )
-    )
+    return jax.jit(call)
 
 
 def _block_windows(
@@ -275,6 +288,7 @@ def fused_search_batch(
     ci: int = DEFAULT_CI,
     interpret: bool | None = None,
     stats: dict | None = None,
+    device=None,
 ) -> tuple[np.ndarray, np.ndarray]:
     """One fused launch over a PlanCache-packed batch.
 
@@ -282,11 +296,8 @@ def fused_search_batch(
     ids (ascending -- L0 order is preserved, no sort needed) with INT_PAD at
     dropped slots, plus the boolean keep mask.  ``stats`` (optional) gets
     the window bookkeeping this launch used (for tracing / roofline attrs).
+    ``device`` (optional) is where the launch runs; default JAX's default.
     """
-    if interpret is None:
-        from . import ops  # late: ops reads XKS_PALLAS_INTERPRET at import
-
-        interpret = ops.INTERPRET
     ids0 = np.ascontiguousarray(ids0, dtype=np.int32)
     rows, m0 = ids0.shape
     k1 = other_ids.shape[1]
@@ -303,23 +314,25 @@ def fused_search_batch(
         ond = np.zeros((rows, 1, bo), dtype=np.int32)
     ci = min(ci, m0)
     fn = _fused_variant(
-        rows, k1, m0, bo, nob, window, ci, semantics, bool(interpret)
+        rows, k1, m0, bo, nob, window, ci, semantics,
+        pallas_interpret(interpret),
     )
-    keep_ids, keep_mask, _found, _ndo = fn(
-        jnp.asarray(starts),
-        jnp.asarray(np.asarray(n0, dtype=np.int32)),
-        jnp.asarray(ids0),
-        jnp.asarray(np.ascontiguousarray(pid0, dtype=np.int32)),
-        jnp.asarray(np.ascontiguousarray(ndesc0, dtype=np.int32)),
-        jnp.asarray(np.ascontiguousarray(oth, dtype=np.int32)),
-        jnp.asarray(np.ascontiguousarray(ond, dtype=np.int32)),
-    )
+    args = [
+        np.asarray(starts, dtype=np.int32),
+        np.asarray(n0, dtype=np.int32),
+        ids0.reshape(rows, 1, m0),
+        np.asarray(pid0, dtype=np.int32).reshape(rows, 1, m0),
+        np.asarray(ndesc0, dtype=np.int32).reshape(rows, 1, m0),
+        np.ascontiguousarray(oth, dtype=np.int32),
+        np.ascontiguousarray(ond, dtype=np.int32),
+    ]
+    keep_ids, keep_mask, _found, _ndo = fn(*jax.device_put(args, device))
     if stats is not None:
         stats.update(
             window=int(window), bo=int(bo), nob=int(nob), rows=int(rows),
             k=int(k1 + 1), m0=int(m0),
         )
-    return np.asarray(keep_ids), np.asarray(keep_mask) != 0
+    return np.asarray(keep_ids)[:, 0], np.asarray(keep_mask)[:, 0] != 0
 
 
 def run_query_fused(lists, semantics: str = "slca") -> np.ndarray:

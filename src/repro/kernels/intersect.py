@@ -27,7 +27,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-INT_PAD = jnp.int32(2**31 - 1)
+from repro.device import pallas_interpret
 
 DEFAULT_BQ = 512
 DEFAULT_BA = 512
@@ -48,7 +48,10 @@ def _membership_kernel(
     a = a_ref[0, :]  # [BA]
     eq = q[:, None] == a[None, :]  # [BQ, BA] dense compare (VPU)
     hit = jnp.any(eq, axis=1)
-    local = jnp.argmax(eq, axis=1).astype(jnp.int32)
+    # ids are unique, so at most one lane matches: the masked iota sum is
+    # its position (Mosaic lowers argmax only for float32)
+    lane = jax.lax.broadcasted_iota(jnp.int32, eq.shape, 1)
+    local = jnp.sum(jnp.where(eq, lane, 0), axis=1)
     # global block index actually visited (must mirror the index_map clamp)
     blk = jnp.minimum(a_start_ref[qi] + j, na_blocks - 1)
     gpos = blk * ba + local
@@ -64,7 +67,7 @@ def membership_pallas_call(
     *,
     bq: int = DEFAULT_BQ,
     ba: int = DEFAULT_BA,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Raw pallas_call; see ops.intersect_membership for the friendly wrapper."""
     ma, mq = a_padded.shape[0], q_padded.shape[0]
@@ -98,6 +101,6 @@ def membership_pallas_call(
             jax.ShapeDtypeStruct((1, mq), jnp.int32),
             jax.ShapeDtypeStruct((1, mq), jnp.int32),
         ],
-        interpret=interpret,
+        interpret=pallas_interpret(interpret),
     )(a_start, q_padded[None, :], a_padded[None, :])
     return found[0] != 0, pos[0]

@@ -5,15 +5,12 @@ many blocks of the larger list a query block may span).  It is data-dependent,
 so these wrappers are host-driven: numpy computes block starts and the
 bucketed window, then dispatches one of a handful of compiled kernel variants.
 On a real TPU the bookkeeping is a few hundred bytes per call; the heavy
-compare runs in the kernel.  Interpret mode executes the same kernel bodies
-on CPU (how this container validates them); it defaults ON and is controlled
-by the ``XKS_PALLAS_INTERPRET`` env var ("0"/"false"/"no"/"off" compile for
-the attached accelerator instead) — every wrapper also takes an explicit
-``interpret=`` keyword override.
+compare runs in the kernel.  The kernels compile for the chip on a TPU and
+run in Pallas interpret mode on the CPU (``repro.device.pallas_interpret``,
+decided per call); every wrapper also takes an explicit ``interpret=``
+keyword override.
 """
 from __future__ import annotations
-
-import os
 
 import jax.numpy as jnp
 import numpy as np
@@ -26,18 +23,6 @@ from .intersect import membership_pallas_call
 from .searchsorted import searchsorted_pallas_call
 from .shapes import INT_PAD, bucket_pow2, pad_to
 
-
-def _env_interpret(default: bool = True) -> bool:
-    raw = os.environ.get("XKS_PALLAS_INTERPRET")
-    if raw is None:
-        return default
-    return raw.strip().lower() not in ("0", "false", "no", "off")
-
-
-# interpret-mode flag: read once at import from XKS_PALLAS_INTERPRET (default
-# True — this container has no TPU).  A TPU deployment exports
-# XKS_PALLAS_INTERPRET=0 instead of editing source.
-INTERPRET = _env_interpret()
 
 # canonical homes moved to kernels/shapes.py; kept under the old private
 # names because tests and downstream code import them from here
@@ -54,7 +39,6 @@ def intersect_membership(
     interpret: bool | None = None,
 ) -> tuple[np.ndarray, np.ndarray]:
     """found/pos of each (sorted) query in sorted unique int32 array ``a``."""
-    interpret = INTERPRET if interpret is None else interpret
     nq = queries_sorted.shape[0]
     a_p = _pad_to(np.asarray(a_sorted, np.int32), ba, INT_PAD)
     q_p = _pad_to(np.asarray(queries_sorted, np.int32), bq, INT_PAD)
@@ -89,7 +73,6 @@ def searchsorted_positions(
     ba: int = 512,
     interpret: bool | None = None,
 ) -> np.ndarray:
-    interpret = INTERPRET if interpret is None else interpret
     nq = queries.shape[0]
     na = a_sorted.shape[0]
     a_p = _pad_to(np.asarray(a_sorted, np.int32), ba, INT_PAD)
@@ -118,7 +101,6 @@ def membership_pallas(sorted_arr, valid_len, queries):
         queries,
         bq=min(512, queries.shape[0]),
         ba=min(512, m),
-        interpret=INTERPRET,
     ).astype(jnp.int32)
     pos_c = jnp.minimum(pos, m - 1)
     found = (pos < valid_len) & (sorted_arr[pos_c] == queries)
@@ -137,7 +119,6 @@ def elca_child_sums(
     bj: int = 512,
     interpret: bool | None = None,
 ) -> np.ndarray:
-    interpret = INTERPRET if interpret is None else interpret
     mi = ca_ids.shape[0]
     ca_p = _pad_to(np.asarray(ca_ids, np.int32), bi, INT_PAD)
     par_p = _pad_to(np.asarray(par_ids, np.int32), bj, -1)

@@ -11,6 +11,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.device import pallas_interpret
+
 DEFAULT_BQ = 512
 DEFAULT_BA = 512
 
@@ -34,7 +36,7 @@ def searchsorted_pallas_call(
     *,
     bq: int = DEFAULT_BQ,
     ba: int = DEFAULT_BA,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     ma, mq = a_padded.shape[0], q_padded.shape[0]
     assert ma % ba == 0 and mq % bq == 0
@@ -48,6 +50,6 @@ def searchsorted_pallas_call(
         ],
         out_specs=pl.BlockSpec((1, bq), lambda qi, j: (0, qi)),
         out_shape=jax.ShapeDtypeStruct((1, mq), jnp.int32),
-        interpret=interpret,
+        interpret=pallas_interpret(interpret),
     )(q_padded[None, :], a_padded[None, :])
     return out[0]

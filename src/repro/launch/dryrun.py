@@ -268,10 +268,10 @@ def lower_cell(
             model_flops=model_flops_for(cfg, cell, cell.kind == "train"),
         )
         # patch the collective term with the extrapolated bytes
-        from repro.roofline.analysis import HW
+        from repro.roofline.analysis import V5E, peaks
 
         report.collective_bytes = acct["collective_bytes"]
-        report.collective_s = acct["collective_bytes"] / HW["ici_bw"]
+        report.collective_s = acct["collective_bytes"] / peaks(V5E)["ici_bw"]
         terms = {
             "compute": report.compute_s,
             "memory": report.memory_s,

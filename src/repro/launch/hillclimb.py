@@ -24,7 +24,7 @@ import time
 from repro.configs import SHAPES, get_config
 from repro.launch.dryrun import account_cell, microbatches_for
 from repro.launch.mesh import make_production_mesh
-from repro.roofline.analysis import HW, model_flops_for
+from repro.roofline.analysis import V5E, model_flops_for, peaks
 
 
 def measure(cfg, shape, mb):
@@ -34,10 +34,11 @@ def measure(cfg, shape, mb):
     cell = SHAPES[shape]
     chips = 256
     mf = model_flops_for(cfg, cell, cell.kind == "train")
+    hw = peaks(V5E)  # the production mesh is a v5e pod
     terms = {
-        "compute_s": acct["flops"] / HW["peak_flops"],
-        "memory_s": acct["bytes"] / HW["hbm_bw"],
-        "collective_s": acct["collective_bytes"] / HW["ici_bw"],
+        "compute_s": acct["flops"] / hw["peak_flops"],
+        "memory_s": acct["bytes"] / hw["hbm_bw"],
+        "collective_s": acct["collective_bytes"] / hw["ici_bw"],
     }
     bound = max(terms.values())
     return {

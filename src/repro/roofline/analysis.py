@@ -1,9 +1,9 @@
 """Roofline terms from a compiled (dry-run) artifact.
 
-Per (arch × shape × mesh):
-  compute    = HLO_FLOPs / (chips × 197e12)          [TPU v5e bf16 peak]
-  memory     = HLO_bytes / (chips × 819e9)           [HBM bandwidth]
-  collective = collective_bytes / (chips × 50e9)     [per-link ICI]
+Per (arch × shape × mesh), with the chip's published peaks (``peaks``):
+  compute    = HLO_FLOPs / (chips × peak_flops)      [bf16 peak]
+  memory     = HLO_bytes / (chips × hbm_bw)          [HBM bandwidth]
+  collective = collective_bytes / (chips × ici_bw)   [per-link ICI]
 
 HLO_FLOPs / HLO_bytes come from compiled.cost_analysis(); collective bytes
 are not in cost_analysis, so the HLO text is parsed: we sum the *result*
@@ -21,11 +21,30 @@ from __future__ import annotations
 import re
 from dataclasses import asdict, dataclass
 
-HW = {
-    "peak_flops": 197e12,  # bf16 / chip (TPU v5e)
-    "hbm_bw": 819e9,  # bytes/s / chip
-    "ici_bw": 50e9,  # bytes/s / link
+# Published per-chip peaks, keyed by JAX's ``device_kind``.
+# TPU v5e: Google Cloud documentation, "TPU v5e" -- 197 TFLOP/s bf16,
+# 393 TOP/s int8, 16 GB of HBM at 819 GB/s, 1,600 Gbit/s of inter-chip
+# interconnect (ici_bw is one of its four 50 GB/s links).
+V5E = "TPU v5 lite"
+PEAKS: dict[str, dict[str, float]] = {
+    V5E: {"peak_flops": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9},
 }
+
+
+class UnknownDevice(KeyError):
+    """No published peaks for this ``device_kind`` (never a default)."""
+
+
+def peaks(device_kind: str) -> dict[str, float]:
+    """The published peaks of one chip of ``device_kind``."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise UnknownDevice(
+            f"no published peaks for device kind {device_kind!r}; "
+            f"known: {sorted(PEAKS)}"
+        ) from None
+
 
 _DTYPE_BYTES = {
     "pred": 1, "s4": 1, "u4": 1, "s8": 1, "u8": 1, "f8e4m3": 1, "f8e5m2": 1,
@@ -111,13 +130,14 @@ def analyze(
     model_flops: float,
     note: str = "",
 ) -> RooflineReport:
+    hw = peaks(V5E)  # the dry-run meshes describe v5e chips
     flops = float(cost.get("flops", 0.0))
     byts = float(cost.get("bytes accessed", 0.0))
     coll = collective_bytes_from_hlo(hlo_text)["total"]
     # cost_analysis of the SPMD module is per-device already
-    compute_s = flops / HW["peak_flops"]
-    memory_s = byts / HW["hbm_bw"]
-    collective_s = coll / HW["ici_bw"]
+    compute_s = flops / hw["peak_flops"]
+    memory_s = byts / hw["hbm_bw"]
+    collective_s = coll / hw["ici_bw"]
     terms = {"compute": compute_s, "memory": memory_s, "collective": collective_s}
     bottleneck = max(terms, key=terms.get)
     bound = max(terms.values())
@@ -161,9 +181,9 @@ class SearchBytesModel:
     the compulsory traffic, i.e. it sits near the bandwidth bound.
 
     All terms are per *batch* (R rows).  ``*_ms`` are the bandwidth-bound
-    lower bounds at ``HW['hbm_bw']`` — what a perfectly-overlapped TPU
-    execution could not beat; interpret-mode wall times sit far above both,
-    but the *ratio* is machine-independent.
+    lower bounds at the chip's HBM bandwidth — what a perfectly-overlapped
+    execution could not beat — and None where no chip's peaks apply (a CPU
+    run); the byte counts and their *ratio* are machine-independent.
     """
 
     rows: int
@@ -181,8 +201,8 @@ class SearchBytesModel:
     fused_stream_bytes: int
     fused_finalize_bytes: int
     fused_bytes: int
-    chained_bw_ms: float
-    fused_bw_ms: float
+    chained_bw_ms: float | None
+    fused_bw_ms: float | None
     bytes_ratio: float  # chained / fused (>1 == fusion moves fewer bytes)
 
     def attrs(self) -> dict:
@@ -191,9 +211,11 @@ class SearchBytesModel:
 
 
 def search_pipeline_bytes(
-    *, rows: int, k: int, m0: int, mo: int, window: int = 1, bo: int = 512
+    *, rows: int, k: int, m0: int, mo: int, window: int = 1, bo: int = 512,
+    hbm_bw: float | None = None,
 ) -> SearchBytesModel:
-    """Bytes-moved model for one batched (R, k, m0, mo) search round."""
+    """Bytes-moved model for one batched (R, k, m0, mo) search round;
+    ``hbm_bw`` (bytes/s, from :func:`peaks`) adds the bandwidth bounds."""
     k1 = max(k - 1, 0)
     streamed = min(window * bo, mo)  # blocks the window walk actually touches
     # -- chained: 3 host-driven launches + 2 HBM round-trips per row -- #
@@ -222,8 +244,8 @@ def search_pipeline_bytes(
         fused_stream_bytes=stream,
         fused_finalize_bytes=finalize,
         fused_bytes=fused,
-        chained_bw_ms=chained / HW["hbm_bw"] * 1e3,
-        fused_bw_ms=fused / HW["hbm_bw"] * 1e3,
+        chained_bw_ms=chained / hbm_bw * 1e3 if hbm_bw else None,
+        fused_bw_ms=fused / hbm_bw * 1e3 if hbm_bw else None,
         bytes_ratio=chained / fused if fused else 0.0,
     )
 

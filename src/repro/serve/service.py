@@ -84,7 +84,10 @@ class QueryService:
         batch_window_ms: float = 2.0,
         backend: str = "jax",
         slow_log_ms: float | None = None,
+        device=None,
     ):
+        """``device`` (optional) pins the engine's launches to one JAX
+        device — the drain's plan cache places every batch there."""
         if engine.cluster is None:
             raise ValueError("QueryService needs an engine with the DAG index")
         validate_backend(backend)
@@ -94,10 +97,11 @@ class QueryService:
             )
         if backend in ("pallas", "fused"):
             # importing the kernel package registers the "pallas" membership
-            # backend with search_vec (and is where "fused" reads its
-            # interpret default); without it the first drain would fail
+            # backend with search_vec; without it the first drain would fail
             from repro.kernels import ops as _kernel_ops  # noqa: F401
         self.engine = engine
+        if device is not None:
+            engine.plan_cache.device = device
         self.backend = backend
         self.max_batch = int(max_batch)
         self.batch_window_s = float(batch_window_ms) / 1e3

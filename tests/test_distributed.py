@@ -23,6 +23,8 @@ _SCRIPT = textwrap.dedent(
     import sys, json
     sys.path.insert(0, %(src)r)
     import numpy as np, jax, jax.numpy as jnp
+    from jax.sharding import AxisType
+    AUTO = (AxisType.Auto,) * 2  # sharding constraints need Auto axes
 
     out = {}
 
@@ -32,7 +34,7 @@ _SCRIPT = textwrap.dedent(
     from repro.dist.search_shard import distributed_query
     tree = generate_discogs_tree(n_releases=60, seed=11)
     eng = KeywordSearchEngine(tree)
-    mesh = jax.make_mesh((1, 8), ("data", "model"))
+    mesh = jax.make_mesh((1, 8), ("data", "model"), axis_types=AUTO)
     checks = 0
     for q, (cat, kws) in QUERIES.items():
         kk = eng.keyword_ids(kws)
@@ -57,7 +59,7 @@ _SCRIPT = textwrap.dedent(
 
     ref_state, ref_metrics = jax.jit(train_step)(init_state(params), batch)
 
-    mesh2 = jax.make_mesh((4, 2), ("data", "model"))
+    mesh2 = jax.make_mesh((4, 2), ("data", "model"), axis_types=AUTO)
     with shard_ctx.use(mesh2):
         state_shape = jax.eval_shape(lambda: init_state(params))
         spec = shd.param_specs(state_shape, mesh2)
@@ -83,7 +85,7 @@ _SCRIPT = textwrap.dedent(
     d = tempfile.mkdtemp()
     ckpt.save_checkpoint(d, 1, ref_state)
     like = init_state(params)
-    mesh3 = jax.make_mesh((2, 4), ("data", "model"))
+    mesh3 = jax.make_mesh((2, 4), ("data", "model"), axis_types=AUTO)
     spec3 = shd.param_specs(jax.eval_shape(lambda: like), mesh3)
     restored, _ = ckpt.restore_checkpoint(
         d, like, shardings=shd.to_named(spec3, mesh3))

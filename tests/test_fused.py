@@ -166,8 +166,8 @@ def test_fused_single_element_lists():
 
 
 def test_fused_interpret_override():
-    """The explicit keyword wins over the module default (satellite of the
-    XKS_PALLAS_INTERPRET flag): interpret=True must work regardless."""
+    """The explicit keyword wins over the platform rule: interpret=True
+    must work regardless."""
     rng = np.random.default_rng(3)
     cache = PlanCache(backend="fused")
     items = random_items(rng, 2, 2)
@@ -302,18 +302,50 @@ def test_fused_phase_span_and_fallback_counter():
 
 
 # --------------------------------------------------------------------------- #
-# XKS_PALLAS_INTERPRET (satellite: env-driven interpret default)
+# Interpret mode follows the platform, never the environment
 # --------------------------------------------------------------------------- #
 
 
 def test_interpret_env_parsing(monkeypatch):
-    from repro.kernels import ops
+    """Interpret mode is the platform's rule, not an environment knob: tpu
+    compiles, cpu interprets, anything else is an error, and an explicit
+    keyword always wins."""
+    import jax
 
-    monkeypatch.delenv("XKS_PALLAS_INTERPRET", raising=False)
-    assert ops._env_interpret() is True  # default: no TPU in this container
-    for raw in ("0", "false", "No", " OFF ", "FALSE"):
-        monkeypatch.setenv("XKS_PALLAS_INTERPRET", raw)
-        assert ops._env_interpret() is False, raw
-    for raw in ("1", "true", "yes", "on", "anything-else"):
-        monkeypatch.setenv("XKS_PALLAS_INTERPRET", raw)
-        assert ops._env_interpret() is True, raw
+    from repro.device import pallas_interpret
+
+    assert jax.default_backend() == "cpu"
+    assert pallas_interpret() is True
+    assert pallas_interpret(False) is False
+    assert pallas_interpret(True) is True
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert pallas_interpret() is False
+    assert pallas_interpret(True) is True
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="gpu"):
+        pallas_interpret()
+    assert pallas_interpret(False) is False
+
+
+def test_compile_cache_dir(monkeypatch):
+    """The persistent compile cache goes where JAX_COMPILATION_CACHE_DIR
+    says (JAX reads it; nothing else is set), else to the fixed
+    ``<checkout>/.jax_cache``."""
+    import os
+
+    import jax
+
+    from repro.device import enable_compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+        assert enable_compile_cache() == "/elsewhere"
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        want = os.path.join(checkout, ".jax_cache")
+        assert enable_compile_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

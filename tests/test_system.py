@@ -91,6 +91,18 @@ def test_roofline_hlo_parse():
     assert got["total"] == got["all-reduce"] + got["all-gather"] + got["collective-permute"]
 
 
+def test_roofline_peaks_keyed_by_device_kind():
+    """Peaks come from the published table for the chip JAX reports; an
+    accelerator not in it is an error, never a default."""
+    from repro.roofline.analysis import UnknownDevice, peaks
+
+    assert peaks("TPU v5 lite") == {
+        "peak_flops": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9,
+    }
+    with pytest.raises(UnknownDevice, match="TPU v9"):
+        peaks("TPU v9")
+
+
 def test_benchmark_sections_importable():
     import benchmarks.run as br  # noqa: F401
     from benchmarks import common

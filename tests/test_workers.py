@@ -10,8 +10,11 @@ this file exercises the seam itself.
 """
 import io
 import json
+import os
 import socket
 import struct
+import subprocess
+import sys
 import threading
 import time
 
@@ -359,3 +362,114 @@ def test_process_reload_bad_artifact_raises_workerdied(corpus):
             svc.reload_shard(0, "/nonexistent/artifact")
         assert svc.stats().summary()["reloads"] == 0
         np.testing.assert_array_equal(svc.query(kws, "slca"), before)
+
+
+def test_process_worker_death_carries_stderr_tail(spec):
+    """A child that dies before it is ready (here: no artifact; on a chip
+    host: the chip is held by another process) fails with the typed
+    WorkerDied carrying its last stderr lines — never a hang."""
+    pw = ProcessWorker(spec, "/nonexistent/artifact", batch_window_ms=1.0)
+    try:
+        assert not pw.wait_ready(120)
+        assert isinstance(pw._dead, WorkerDied)
+        assert "stderr tail" in pw._dead.detail
+        assert "FileNotFoundError" in pw._dead.detail
+        with pytest.raises(WorkerDied):
+            pw.submit(["vinyl"], "slca")
+    finally:
+        pw.close(5.0)
+
+
+def test_refuse_cpu_fallback_rule(monkeypatch):
+    """Only an unasked-for CPU on a host with a TPU runtime is refused."""
+    import jax
+
+    import repro.device as device
+
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    monkeypatch.setattr(device, "_tpu_runtime_installed", lambda: True)
+    with pytest.raises(RuntimeError, match="JAX_PLATFORMS=cpu"):
+        device.refuse_cpu_fallback()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    device.refuse_cpu_fallback()  # on the chip
+    monkeypatch.undo()
+    opened = []
+    monkeypatch.setattr(jax, "default_backend", lambda: opened.append(1) or "tpu")
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    device.refuse_cpu_fallback()
+    assert opened  # the backend is opened even when JAX_PLATFORMS is set
+    monkeypatch.undo()
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    monkeypatch.setattr(device, "_tpu_runtime_installed", lambda: True)
+    device.refuse_cpu_fallback()  # the CPU, asked for
+    monkeypatch.delenv("JAX_PLATFORMS")
+    monkeypatch.setattr(device, "_tpu_runtime_installed", lambda: False)
+    device.refuse_cpu_fallback()  # a host without a TPU runtime
+
+
+_FALLBACK_SCRIPT = """
+import os, sys
+sys.path.insert(0, {src!r})
+import jax  # reads JAX_PLATFORMS=cpu: no chip is looked for
+os.environ.pop("JAX_PLATFORMS")  # ...then unset, as on a chip host
+import repro.device
+repro.device._tpu_runtime_installed = lambda: True
+from repro.cluster.workers import subproc
+sys.exit(subproc.main(["--dir", "/nonexistent/artifact"]))
+"""
+
+
+def test_shard_child_refuses_cpu_fallback():
+    """A shard child that JAX put on the CPU of a chip host (the chip held
+    by another process, JAX_PLATFORMS unset) dies before it is ready, with
+    the reason on stderr, so the pool raises WorkerDied carrying it."""
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    out = subprocess.run(
+        [sys.executable, "-c", _FALLBACK_SCRIPT.format(src=os.path.abspath(src))],
+        capture_output=True, text=True, timeout=120,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"),
+    )
+    assert out.returncode != 0
+    assert out.stdout == ""  # no ready frame
+    assert "fell back to the CPU" in out.stderr, out.stderr[-3000:]
+    assert "FileNotFoundError" not in out.stderr  # refused before loading
+
+
+_PIN_SCRIPT = """
+import os, sys
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+sys.path.insert(0, {src!r})
+import jax
+import repro.core.plan_cache as pc
+from repro.cluster import ClusterService
+from repro.data import QUERIES, generate_discogs_tree
+
+seen = set()
+orig = pc.ca_search_batch
+
+def spy(**kw):
+    out = orig(**kw)
+    seen.update(d.id for d in out[0].devices())
+    return out
+
+pc.ca_search_batch = spy
+tree = generate_discogs_tree(n_releases=40, seed=11)
+with ClusterService.from_tree(
+    tree, 4, transport="thread", backends="jax", batch_window_ms=1.0
+) as svc:
+    pinned = [w.engine.plan_cache.device.id for w in svc.pool.workers]
+    svc.query(QUERIES["Q1"][1], "slca")
+print(pinned, sorted(seen))
+"""
+
+
+def test_thread_pool_pins_one_device_per_shard():
+    """One process drives every chip: shard i's launches land on device i."""
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    out = subprocess.run(
+        [sys.executable, "-c", _PIN_SCRIPT.format(src=os.path.abspath(src))],
+        capture_output=True, text=True, timeout=300, env=env,
+    )
+    assert out.returncode == 0, out.stderr[-3000:]
+    assert out.stdout.split("\n")[-2] == "[0, 1, 2, 3] [0, 1, 2, 3]"
