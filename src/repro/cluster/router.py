@@ -67,7 +67,7 @@ import numpy as np
 from repro.api import Query, QueryResult, chain_future, validate_semantics
 from repro.core.engine import QueryStats
 from repro.core.xml_tree import XMLTree
-from repro.obs import NULL_SPAN, TRACER
+from repro.obs import COMPILES, NULL_SPAN, TRACER
 
 from .admission import AdmissionController, Overloaded
 from .manifest import (
@@ -987,8 +987,15 @@ class ClusterService:
                 "rows_padded": agg.data.get("rows_padded", 0),
                 "plan_hit_rate": agg.data.get("plan_hit_rate", 0.0),
                 "fused_fallbacks": agg.data.get("fused_fallbacks", 0),
+                "upload_bytes": agg.data.get("upload_bytes", 0),
+                "upload_bytes_useful": agg.data.get("upload_bytes_useful", 0),
             }
         )
+        # compiles are per process: shard servers report their own, thread
+        # shards share this process's, counted here once
+        local = COMPILES.snapshot()
+        for key in ("compiles", "compile_s"):
+            snap.data[key] = agg.data.get(key, 0) + local[key]
         # replica-tier health (present only when shards are ReplicaSets)
         for key in ("replicas", "replicas_live", "hedges_fired", "hedge_wins",
                     "failovers", "replica_deaths", "replica_respawns"):

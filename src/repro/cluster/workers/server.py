@@ -46,7 +46,7 @@ from typing import BinaryIO
 
 from repro.core.engine import KeywordSearchEngine
 from repro.device import enable_compile_cache, refuse_cpu_fallback
-from repro.obs import TRACER
+from repro.obs import COMPILES, TRACER
 from repro.serve.service import QueryService
 
 from ..partition import doc_roots
@@ -209,6 +209,8 @@ def serve_stream(
                 reply(hdr, dump_array(docs_k))
             elif op == "stats":
                 snap = state.svc.stats()
+                # this process's compiles: one shard per server process
+                snap.data.update(COMPILES.snapshot())
                 hdr = {
                     "id": rid, "op": "stats", "ok": True,
                     "data": snap.data,

@@ -16,9 +16,10 @@ maps to an empty result, so R-padding is free of special cases.
 
 The cache is engine-owned (each :class:`KeywordSearchEngine` carries one) but
 can be shared across engines serving the same process; hit/miss/launch
-counters feed ``QueryStats`` and the service benchmarks.  ``device`` pins
-every launch to one JAX device (a shard per chip); None leaves placement
-to JAX's default device.
+counters feed ``QueryStats`` and the service benchmarks, among them the
+bytes each launch uploads and how many of them are not padding.
+``device`` pins every launch to one JAX device (a shard per chip); None
+leaves placement to JAX's default device.
 """
 from __future__ import annotations
 
@@ -28,12 +29,13 @@ from dataclasses import dataclass, field
 import jax
 import numpy as np
 
-from repro.roofline.analysis import peaks, search_pipeline_bytes
+from repro.obs import COMPILES
 
 from .idlist import IDList
 from .search_vec import INT_PAD, bucket, ca_search_batch
 
 _EMPTY = np.zeros(0, dtype=np.int64)
+_INT32 = 4  # every packed array is int32
 
 
 @dataclass(frozen=True)
@@ -60,7 +62,10 @@ class PlanCache:
         self.misses = 0  # launches that compiled a new executable
         self.rows_padded = 0  # wasted rows across all launches (R padding)
         self.fused_fallbacks = 0  # fused launches demoted to chained (shape cap)
+        self.upload_bytes = 0  # bytes of the packed arrays launched
+        self.upload_bytes_useful = 0  # ... of them holding list entries
         self._seen: set[tuple] = set()
+        COMPILES.install()
 
     # ------------------------------------------------------------------ #
     @property
@@ -80,34 +85,33 @@ class PlanCache:
             "plan_hit_rate": round(self.hit_rate(), 4),
             "rows_padded": self.rows_padded,
             "fused_fallbacks": self.fused_fallbacks,
+            "upload_bytes": self.upload_bytes,
+            "upload_bytes_useful": self.upload_bytes_useful,
         }
 
     def reset_counters(self) -> None:
         """Zero the counters but keep the seen-shape set (plans stay warm)."""
         self.launches = self.hits = self.misses = self.rows_padded = 0
+        self.upload_bytes = self.upload_bytes_useful = 0
 
-    @staticmethod
-    def executable_count() -> int:
-        """Entries in the underlying jit cache (compile-count ground truth).
+    def _xla_batch(self, batch: dict, semantics: str, backend: str,
+                   stamps: list | None = None):
+        """The jitted batch search, on this cache's device when pinned.
 
-        Returns -1 if the private jax introspection hook is unavailable —
-        callers must treat that as "unknown", not "zero"."""
-        cache_size = getattr(ca_search_batch, "_cache_size", None)
-        return int(cache_size()) if cache_size is not None else -1
-
-    def _xla_batch(self, batch: dict, semantics: str, backend: str):
-        """The jitted batch search, on this cache's device when pinned."""
-        if self.device is not None:
-            batch = jax.device_put(batch, self.device)
-        return ca_search_batch(**batch, semantics=semantics, backend=backend)
-
-    def _hbm_bw(self) -> float | None:
-        """HBM bandwidth of the launch device: None on the CPU (no chip to
-        bound against); an accelerator without published peaks raises."""
-        device = self.device or jax.devices()[0]
-        if device.platform == "cpu":
-            return None
-        return peaks(device.device_kind)["hbm_bw"]
+        ``stamps`` (traced launches only) gets the perf-counter times at
+        which the batch was on the device and the results were ready: only
+        a traced launch uploads explicitly and waits for the device."""
+        if stamps is None:
+            if self.device is not None:
+                batch = jax.device_put(batch, self.device)
+            return ca_search_batch(**batch, semantics=semantics, backend=backend)
+        batch = jax.block_until_ready(jax.device_put(batch, self.device))
+        stamps.append(time.perf_counter())
+        out = jax.block_until_ready(
+            ca_search_batch(**batch, semantics=semantics, backend=backend)
+        )
+        stamps.append(time.perf_counter())
+        return out
 
     # ------------------------------------------------------------------ #
     def pack(
@@ -185,9 +189,11 @@ class PlanCache:
 
         ``phases`` (when a traced query asks for timing) collects
         ``{"name", "t0_ms", "dur_ms", "attrs"}`` dicts for the pack step
-        and the kernel launch — the per-phase spans the tracing layer
-        attaches under the query's execute span.  ``None`` (every untraced
-        call) skips all timing work.
+        and the kernel launch, and below the launch its upload, device
+        wait and read-back (``"parent"`` indexes the launch's dict) — the
+        per-phase spans the tracing layer attaches under the query's
+        execute span.  ``None`` (every untraced call) skips all timing
+        work.
         """
         backend = backend or self.backend
         out = {key: _EMPTY for key in keys}
@@ -204,16 +210,23 @@ class PlanCache:
             self.misses += 1
             hit = False
         self.launches += 1
+        nbytes = sum(a.nbytes for a in batch.values())
+        n0, on = int(batch["n0"].sum()), int(batch["other_n"].sum())
+        useful = _INT32 * (3 * n0 + 2 * on + len(kept) * sig.k)
+        self.upload_bytes += nbytes
+        self.upload_bytes_useful += useful
+        stamps = None
         if phases is not None:
             p1 = time.perf_counter()
             phases.append({
                 "name": "plan.pack", "t0_ms": w0, "dur_ms": (p1 - p0) * 1e3,
                 "attrs": {
                     "rows": sig.rows, "k": sig.k, "m0": sig.m0, "mo": sig.mo,
-                    "plan_hit": hit,
+                    "plan_hit": hit, "n_useful": n0 + on, "bytes": nbytes,
+                    "useful_bytes": useful,
                 },
             })
-            w1 = time.time() * 1e3
+            stamps = [p1]
         if backend == "fused":
             # lazy: fused_search pulls in pallas; PlanCache stays importable
             # without it (scalar-only deployments)
@@ -226,49 +239,48 @@ class PlanCache:
                 # giant shortest list: the fused variant would blow VMEM —
                 # demote this launch to the chained batch path
                 self.fused_fallbacks += 1
-                ids, mask = self._xla_batch(batch, semantics, "xla")
+                ids, mask = self._xla_batch(batch, semantics, "xla", stamps)
                 kstats = {"fallback": True}
             else:
+                # uploads and reads back inside the kernel's wrapper, so a
+                # traced fused launch has no child spans
                 kstats = {}
+                stamps = None
                 ids, mask = fused_search_batch(
                     **batch, semantics=semantics, stats=kstats,
                     device=self.device,
                 )
         else:
-            ids, mask = self._xla_batch(batch, semantics, backend)
+            ids, mask = self._xla_batch(batch, semantics, backend, stamps)
             kstats = None
         ids = np.asarray(ids)
         mask = np.asarray(mask)
         if phases is not None:
-            if kstats is not None and not kstats.get("fallback"):
-                # the whole pipeline is one launch: a single span, with the
-                # per-phase cost split carried as roofline byte counters
-                # instead of child timings
-                attrs = {
-                    "backend": backend, "semantics": semantics,
-                    "rows": sig.rows,
-                }
+            p_end = time.perf_counter()
+
+            def wall(p: float) -> float:
+                return w0 + (p - p0) * 1e3
+
+            attrs = {"backend": backend, "semantics": semantics, "rows": sig.rows}
+            fused = kstats is not None and not kstats.get("fallback")
+            if fused:
                 attrs.update(kstats)
-                attrs.update(search_pipeline_bytes(
-                    rows=sig.rows, k=sig.k, m0=sig.m0, mo=sig.mo,
-                    window=kstats.get("window", 1),
-                    bo=kstats.get("bo", 512),
-                    hbm_bw=self._hbm_bw(),
-                ).attrs())
-                phases.append({
-                    "name": "kernel.fused_round",
-                    "t0_ms": w1, "dur_ms": (time.perf_counter() - p1) * 1e3,
-                    "attrs": attrs,
-                })
-            else:
-                phases.append({
-                    "name": "kernel.ca_search",
-                    "t0_ms": w1, "dur_ms": (time.perf_counter() - p1) * 1e3,
-                    "attrs": {
-                        "backend": backend, "semantics": semantics,
-                        "rows": sig.rows,
-                    },
-                })
+            at = len(phases)
+            phases.append({
+                "name": "kernel.fused_round" if fused else "kernel.ca_search",
+                "t0_ms": wall(p1), "dur_ms": (p_end - p1) * 1e3,
+                "attrs": attrs,
+            })
+            if stamps is not None:
+                stamps.append(p_end)
+                for name, a, b in zip(
+                    ("kernel.upload", "kernel.device", "kernel.readback"),
+                    stamps, stamps[1:],
+                ):
+                    phases.append({
+                        "name": name, "t0_ms": wall(a), "dur_ms": (b - a) * 1e3,
+                        "parent": at,
+                    })
         for r, key in enumerate(kept):
             out[key] = ids[r][mask[r]].astype(np.int64)
         return out

@@ -40,9 +40,10 @@ def dag_search_vec(
 ) -> np.ndarray:
     """Frontier-batched DAG search; returns sorted original node ids.
 
-    ``phases`` (traced queries only) collects per-phase timing dicts from
-    the plan cache's pack/launch steps — and from the per-RC pallas
-    dispatch loop, which runs outside the plan cache.
+    ``phases`` (traced queries only) collects per-phase timing dicts: a
+    ``plan.round`` per frontier round, over the plan cache's pack/launch
+    steps (or the per-RC pallas dispatch loop, which runs outside the plan
+    cache), and the ``plan.splice`` at the end.
     """
     plan = _plan_or_default(plan)
     launches0 = plan.launches
@@ -52,6 +53,8 @@ def dag_search_vec(
     rounds = 0
     while frontier:
         rounds += 1
+        if phases is not None:
+            r_at, r_p0 = _open_round(phases, rounds, len(frontier), len(kws))
         if backend == "pallas":
             from repro.kernels import ops as kernel_ops  # lazy: avoid cycle
 
@@ -86,6 +89,8 @@ def dag_search_vec(
             for nested in index.nested_rcs(rc, res):
                 if nested not in memo and nested not in nxt:
                     nxt.append(nested)
+        if phases is not None:
+            _close_round(phases, r_at, r_p0)
         frontier = nxt
     if stats is not None:
         stats["rounds"] = rounds
@@ -95,6 +100,8 @@ def dag_search_vec(
         else:
             stats.update(plan.snapshot())  # lifetime counters (plan_* keys)
             stats["launches"] = plan.launches - launches0  # this call only
+    if phases is not None:
+        return _timed_splice(index, memo, semantics, phases)
     return _splice(index, memo, semantics)
 
 
@@ -122,6 +129,11 @@ def dag_search_vec_multi(
     the whole packed batch to the single-launch Pallas pipeline
     (:mod:`repro.kernels.fused_search`).  Either way every launch flows
     through the PlanCache, whose plan keys carry the backend name.
+
+    ``phases`` (traced batches only) collects a ``plan.round`` per launch
+    group of a frontier round, over its pack and launch phases, and one
+    ``plan.splice`` per query, marked with its batch position
+    (``"query"``) so that only that query's trace records it.
     """
     plan = _plan_or_default(plan)
     launches0 = plan.launches
@@ -136,7 +148,9 @@ def dag_search_vec_multi(
         for qi, rc in frontier:
             by_k.setdefault(len(queries[qi]), []).append((qi, rc))
         nxt: list[tuple[int, int]] = []
-        for _, items in by_k.items():
+        for k, items in by_k.items():
+            if phases is not None:
+                r_at, r_p0 = _open_round(phases, rounds, len(items), k)
             per_item = [index.idlists(rc, queries[qi]) for qi, rc in items]
             results = plan.run(
                 per_item, items, semantics=semantics, backend=backend,
@@ -153,26 +167,76 @@ def dag_search_vec_multi(
                         # frontier round executes
                         memos[qi][nested] = None
                         nxt.append((qi, nested))
+            if phases is not None:
+                _close_round(phases, r_at, r_p0)
         frontier = nxt
     if stats is not None:
         stats["rounds"] = rounds
         stats.update(plan.snapshot())  # lifetime counters (plan_* keys)
         stats["launches"] = plan.launches - launches0  # this call only
-    return [
-        _splice(index, memos[qi], semantics)
-        if all(k >= 0 for k in queries[qi])
-        else np.zeros(0, dtype=np.int64)
-        for qi in range(len(queries))
-    ]
+    out = []
+    for qi, kws in enumerate(queries):
+        if not all(k >= 0 for k in kws):
+            out.append(np.zeros(0, dtype=np.int64))
+        elif phases is None:
+            out.append(_splice(index, memos[qi], semantics))
+        else:
+            out.append(_timed_splice(index, memos[qi], semantics, phases, qi))
+    return out
+
+
+def _open_round(phases: list, rnd: int, items: int, k: int) -> tuple:
+    """Append a ``plan.round`` phase, to be ended by :func:`_close_round`;
+    returns its index and its perf-counter start."""
+    phases.append({
+        "name": "plan.round", "t0_ms": time.time() * 1e3, "dur_ms": 0.0,
+        "attrs": {"round": rnd, "items": items, "k": k},
+    })
+    return len(phases) - 1, time.perf_counter()
+
+
+def _close_round(phases: list, at: int, p0: float) -> None:
+    """End the round at ``phases[at]``; the phases appended since, which
+    name no parent of their own, become its children."""
+    phases[at]["dur_ms"] = (time.perf_counter() - p0) * 1e3
+    for p in phases[at + 1:]:
+        p.setdefault("parent", at)
+
+
+def _timed_splice(
+    index: IDClusterIndex,
+    memo: dict[int, np.ndarray],
+    semantics: str,
+    phases: list,
+    query: int | None = None,
+) -> np.ndarray:
+    """:func:`_splice`, appending a ``plan.splice`` phase (of ``query``
+    alone when a batch is spliced)."""
+    w0, p0 = time.time() * 1e3, time.perf_counter()
+    counts = {"dummies": 0}
+    res = _splice(index, memo, semantics, counts)
+    span = {
+        "name": "plan.splice", "t0_ms": w0,
+        "dur_ms": (time.perf_counter() - p0) * 1e3,
+        "attrs": {"dummies": counts["dummies"], "results": int(res.size)},
+    }
+    if query is not None:
+        span["query"] = query
+    phases.append(span)
+    return res
 
 
 def _splice(
-    index: IDClusterIndex, memo: dict[int, np.ndarray], semantics: str
+    index: IDClusterIndex,
+    memo: dict[int, np.ndarray],
+    semantics: str,
+    counts: dict | None = None,
 ) -> np.ndarray:
     """Resolve dummy results through the RCPM (host-side materialization).
 
     The RCPM probe is vectorized per RC (one searchsorted over all results);
-    only actual dummies loop."""
+    only actual dummies loop.  ``counts`` (traced splices only) gets the
+    number of dummies resolved under ``"dummies"``."""
     resolved: dict[int, np.ndarray] = {}
     rcs = index.rcs
     dummy_ids = rcs.dummy_ids
@@ -192,6 +256,8 @@ def _splice(
         if not is_dummy.any():
             resolved[rc] = res
             return res
+        if counts is not None:
+            counts["dummies"] += int(is_dummy.sum())
         parts = [res[~is_dummy]]
         for _x, p in zip(res[is_dummy], pos_c[is_dummy]):
             parts.append(resolve(int(rcs.dummy_nested_rc[p])) + int(rcs.dummy_offset[p]))

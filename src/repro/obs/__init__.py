@@ -1,6 +1,7 @@
-"""Observability: tracing, metrics, workload heat, and time series.
+"""Observability: tracing, metrics, workload heat, time series, compiles.
 
-Four pieces, all stdlib + numpy only:
+Five pieces, all stdlib + numpy only (the compile counter imports JAX when
+it is installed):
 
   * :mod:`repro.obs.trace` — opt-in per-query spans propagated on a
     W3C-style ``traceparent``, recorded in the process-local
@@ -19,8 +20,11 @@ Four pieces, all stdlib + numpy only:
     and consumed by ``ClusterService.load_report()`` / ``GET /debug/heat``;
   * :mod:`repro.obs.timeseries` — :class:`TimeSeriesStore`, a bounded
     ring-buffer history of every registry metric sampled on a daemon
-    thread (``GET /debug/timeseries``).
+    thread (``GET /debug/timeseries``);
+  * :mod:`repro.obs.compiles` — :data:`COMPILES`, the process's count of
+    XLA compiles and compile seconds, from ``jax.monitoring`` events.
 """
+from .compiles import COMPILES
 from .heat import CountMinSketch, HeatShapeError, HeatSketch, SpaceSaving
 from .metrics import (
     DEFAULT_BUCKETS_MS,
@@ -50,6 +54,7 @@ from .trace import (
 
 __all__ = [
     "BucketMismatchError",
+    "COMPILES",
     "CountMinSketch",
     "DEFAULT_BUCKETS_MS",
     "Counter",

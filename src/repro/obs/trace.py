@@ -260,13 +260,20 @@ class Tracer:
         )
         return TraceContext(ctx.trace_id, span_id)
 
-    def emit_many(self, parent, spans: list[dict]) -> None:
+    def emit_many(
+        self, parent, spans: list[dict], query: int | None = None
+    ) -> None:
         """Record many completed child spans of ``parent`` at once.
 
         ``spans`` are ``{"name", "t0_ms", "dur_ms", "attrs"?}`` dicts (the
         engine's phase timings).  One id/parse pass and one lock trip for
         the whole list — a traced batch emits its phase spans per item, so
         this path is measurably hotter than one-off ``emit`` calls.
+
+        A dict's optional ``"parent"`` is the index in ``spans`` of its
+        parent phase (default: ``parent`` itself); an optional ``"query"``
+        is the batch position of the one query it belongs to, and the span
+        is recorded only when ``query`` names that position.
         """
         if not self.enabled or not spans:
             return
@@ -274,17 +281,19 @@ class Tracer:
         if ctx is None:
             return
         tid, pid = ctx.trace_id, ctx.span_id
+        ids = [new_span_id() for _ in spans]
         rows = [
             {
                 "trace_id": tid,
-                "span_id": new_span_id(),
-                "parent_id": pid,
+                "span_id": ids[i],
+                "parent_id": pid if s.get("parent") is None else ids[s["parent"]],
                 "name": s["name"],
                 "t0_ms": round(float(s["t0_ms"]), 3),
                 "dur_ms": round(float(s["dur_ms"]), 3),
                 "attrs": s.get("attrs", {}),
             }
-            for s in spans
+            for i, s in enumerate(spans)
+            if s.get("query", query) == query
         ]
         with self._lock:
             bucket = self._spans.get(tid)
@@ -365,14 +374,15 @@ class Tracer:
 TRACER = Tracer()
 
 
-def emit_phases(parent, phases: list[dict]) -> None:
+def emit_phases(parent, phases: list[dict], query: int | None = None) -> None:
     """Record the engine's per-phase timings as child spans of ``parent``.
 
     Phases are the ``{"name", "t0_ms", "dur_ms", "attrs"}`` dicts the plan
     cache / DAG search append when asked to time themselves (they know
-    nothing about tracing, only wall-clock timing).
+    nothing about tracing, only wall-clock timing); a phase may name its
+    parent phase and its query (:meth:`Tracer.emit_many`).
     """
-    TRACER.emit_many(parent, phases)
+    TRACER.emit_many(parent, phases, query)
 
 
 class TraceSampler:

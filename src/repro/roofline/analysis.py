@@ -161,95 +161,6 @@ def analyze(
     )
 
 
-# --------------------------------------------------------------------------- #
-# Search-pipeline bytes-moved model (fused vs chained Pallas path)
-# --------------------------------------------------------------------------- #
-
-_I32 = 4  # every array on the search hot path is int32
-
-
-@dataclass
-class SearchBytesModel:
-    """HBM bytes one batched search round moves, chained vs fused.
-
-    The chained path (``ops.run_query_pallas`` per query) launches
-    membership, host compaction, and the ELCA segsum separately, so every
-    phase re-reads its operands from HBM and writes intermediates back.
-    The fused kernel streams each other-list tile once, keeps the L0 row,
-    the membership masks, and the CA mask VMEM-resident, and writes only
-    the final keep ids/mask — its byte count is within a small constant of
-    the compulsory traffic, i.e. it sits near the bandwidth bound.
-
-    All terms are per *batch* (R rows).  ``*_ms`` are the bandwidth-bound
-    lower bounds at the chip's HBM bandwidth — what a perfectly-overlapped
-    execution could not beat — and None where no chip's peaks apply (a CPU
-    run); the byte counts and their *ratio* are machine-independent.
-    """
-
-    rows: int
-    k: int
-    m0: int
-    mo: int
-    window: int
-    bo: int
-    # chained per-phase attribution
-    chained_membership_bytes: int
-    chained_compact_bytes: int
-    chained_segsum_bytes: int
-    chained_bytes: int
-    # fused per-phase attribution
-    fused_stream_bytes: int
-    fused_finalize_bytes: int
-    fused_bytes: int
-    chained_bw_ms: float | None
-    fused_bw_ms: float | None
-    bytes_ratio: float  # chained / fused (>1 == fusion moves fewer bytes)
-
-    def attrs(self) -> dict:
-        """Flat span-attribute dict (the fused round's cost attribution)."""
-        return asdict(self)
-
-
-def search_pipeline_bytes(
-    *, rows: int, k: int, m0: int, mo: int, window: int = 1, bo: int = 512,
-    hbm_bw: float | None = None,
-) -> SearchBytesModel:
-    """Bytes-moved model for one batched (R, k, m0, mo) search round;
-    ``hbm_bw`` (bytes/s, from :func:`peaks`) adds the bandwidth bounds."""
-    k1 = max(k - 1, 0)
-    streamed = min(window * bo, mo)  # blocks the window walk actually touches
-    # -- chained: 3 host-driven launches + 2 HBM round-trips per row -- #
-    # membership launch per other list: read other ids + ndesc gather source
-    # + L0 queries, write found/pos
-    membership = rows * k1 * (streamed + mo + m0 + 2 * m0) * _I32
-    # host compaction: read ids/pid/found/nd rows, write compacted ca/par/nd
-    compact = rows * ((3 + 2 * k1 + k) * m0 + (k + 2) * m0) * _I32
-    # segsum launch: read ca/par + k ndesc rows, write k child sums
-    segsum = rows * ((2 + k) * m0 + k * m0) * _I32
-    chained = membership + compact + segsum
-    # -- fused: one launch, compulsory traffic only -- #
-    # stream: L0 residency (ids/pid/nd once) + one pass over the window's
-    # other-list tiles (ids + ndesc), accumulators stay VMEM-resident and
-    # write back once
-    stream = rows * (3 * m0 + 2 * k1 * streamed + 2 * k1 * m0) * _I32
-    # finalize: keep ids + mask out (CA mask lives in VMEM scratch)
-    finalize = rows * 2 * m0 * _I32
-    fused = stream + finalize
-    return SearchBytesModel(
-        rows=rows, k=k, m0=m0, mo=mo, window=window, bo=bo,
-        chained_membership_bytes=membership,
-        chained_compact_bytes=compact,
-        chained_segsum_bytes=segsum,
-        chained_bytes=chained,
-        fused_stream_bytes=stream,
-        fused_finalize_bytes=finalize,
-        fused_bytes=fused,
-        chained_bw_ms=chained / hbm_bw * 1e3 if hbm_bw else None,
-        fused_bw_ms=fused / hbm_bw * 1e3 if hbm_bw else None,
-        bytes_ratio=chained / fused if fused else 0.0,
-    )
-
-
 def model_flops_for(cfg, shape_cell, train: bool) -> float:
     """6·N·D per step (3x for fwd+bwd via the standard 6ND convention)."""
     n_active = cfg.active_param_count()

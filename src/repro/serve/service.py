@@ -326,7 +326,7 @@ class QueryService:
 
     def _run_group(self, semantics: str, items: list[_Pending]) -> None:
         traced = (
-            [it for it in items if it.trace is not None]
+            [i for i, it in enumerate(items) if it.trace is not None]
             if TRACER.enabled
             else []
         )
@@ -368,11 +368,13 @@ class QueryService:
         self,
         semantics: str,
         items: list[_Pending],
-        traced: list[_Pending],
+        traced: list[int],
         phases: list | None,
         t_run: float,
     ) -> None:
-        """Execute (+ engine phase) spans for each traced item.
+        """Execute (+ engine phase) spans for each traced item (``traced``:
+        positions in ``items``).  The batch's shared phases go under every
+        traced item; a phase of one query (its splice) under that one.
 
         Wall-clock anchors are reconstructed from the perf-counter stamps
         (``wall_now - perf_elapsed``), so span timestamps line up with the
@@ -385,11 +387,12 @@ class QueryService:
         now_wall = time.time() * 1e3
         t0_ms = now_wall - (now_perf - t_run) * 1e3
         dur_ms = (now_perf - t_run) * 1e3
-        for it in traced:
+        for qi in traced:
+            it = items[qi]
             ectx = TRACER.emit(
                 it.trace, "service.execute", t0_ms, dur_ms,
                 batch=len(items), semantics=semantics, backend=self.backend,
                 queued_ms=round((t_run - it.t_submit) * 1e3, 3),
             )
             if phases and ectx is not None:
-                emit_phases(ectx, phases)
+                emit_phases(ectx, phases, qi)
