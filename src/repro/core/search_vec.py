@@ -4,8 +4,8 @@ TPU-native re-derivation of FwdSLCA/FwdELCA (DESIGN.md §2): instead of cursor
 walking, we
 
   1. intersect by *membership*: every element of the shortest list L0 is
-     binary-searched into the other lists (vectorized `searchsorted`, or the
-     Pallas block kernel when backend="pallas");
+     located in the other lists (a loop-free block compare,
+     `searchsorted_left`, or the Pallas block kernel when backend="pallas");
   2. compact the CA set by prefix sum (L0 is ascending, so it stays
      sorted; pad = INT32_MAX fills the tail);
   3. SLCA: a CA is SLCA iff the *next* CA's parent differs (ancestor-closure
@@ -30,6 +30,33 @@ from repro.kernels.shapes import INT_PAD, bucket  # noqa: F401  (re-exported)
 
 from .idlist import IDList
 
+# A searched list is cut into rows of this many ids (one lane-width vector).
+SEARCH_BLOCK = 128
+
+
+def searchsorted_left(a: jax.Array, q: jax.Array) -> jax.Array:
+    """``searchsorted(a, q, side="left")``: the count of ``a < q`` per query.
+
+    ``a`` is sorted, of static length, padded with ``INT_PAD`` (which is
+    below no query).  Loop-free, unlike ``jnp.searchsorted``'s ``while`` of
+    log2(n) scalar gathers: a list of up to ``SEARCH_BLOCK`` ids is compared
+    whole; a longer one is cut into ``SEARCH_BLOCK``-id rows, the row heads
+    pick each query's row, and one gathered row is compared.  Every compare
+    feeds a sum, so nothing of size queries x ids is materialised.
+    """
+
+    def below(rows: jax.Array) -> jax.Array:
+        return jnp.sum(rows < q[:, None], axis=1, dtype=jnp.int32)
+
+    n = a.shape[0]
+    if n <= SEARCH_BLOCK:
+        return below(a[None, :])
+    a = jnp.pad(a, (0, -n % SEARCH_BLOCK), constant_values=INT_PAD)
+    blocks = a.reshape(-1, SEARCH_BLOCK)
+    blk = jnp.maximum(below(blocks[None, :, 0]) - 1, 0)
+    return blk * SEARCH_BLOCK + below(blocks[blk])
+
+
 # membership backend registry: name -> fn(sorted_arr, valid_len, queries)
 #   -> (found_mask [m0] bool, positions [m0] int32)
 _MEMBERSHIP_BACKENDS: dict[str, Callable] = {}
@@ -44,7 +71,7 @@ def membership_xla(
 ) -> tuple[jax.Array, jax.Array]:
     """Membership + position of each query in a padded sorted array."""
     m = sorted_arr.shape[0]
-    pos = jnp.searchsorted(sorted_arr, queries, side="left").astype(jnp.int32)
+    pos = searchsorted_left(sorted_arr, queries)
     pos_c = jnp.minimum(pos, m - 1)
     found = (pos < valid_len) & (sorted_arr[pos_c] == queries)
     return found, pos_c
@@ -111,7 +138,7 @@ def ca_search(
         res_mask = valid & (is_last | (next_par != ca_sorted))
     elif semantics == "elca":
         # position of each CA's parent inside the compacted CA array
-        pp = jnp.searchsorted(ca_sorted, par_sorted).astype(jnp.int32)
+        pp = searchsorted_left(ca_sorted, par_sorted)
         pp_c = jnp.minimum(pp, m0 - 1)
         par_is_ca = valid & (par_sorted >= 0) & (ca_sorted[pp_c] == par_sorted)
         seg = jnp.where(par_is_ca, pp_c, m0)  # overflow bucket for roots/invalid

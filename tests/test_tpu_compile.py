@@ -107,23 +107,38 @@ def test_elca_segsum_compiles(shape):
     _assert_kernel(fn.lower(shape(2048), shape(2048), shape(3, 2048)).compile())
 
 
-@pytest.mark.parametrize("backend", ["xla", "pallas"])
-def test_ca_search_batch_compiles(shape, monkeypatch, backend):
+@pytest.mark.parametrize(
+    "backend,semantics", [("xla", "slca"), ("xla", "elca"), ("pallas", "elca")]
+)
+def test_ca_search_batch_compiles(shape, monkeypatch, backend, semantics):
     """The jitted batch search; ``pallas`` membership asks the platform
-    rule at trace time, which must answer as on the chip."""
+    rule at trace time, which must answer as on the chip.  ``xla`` is
+    compiled at a bucket of the 100k-release cell: three other lists of
+    131072 ids searched by 8192."""
     import repro.kernels.ops  # noqa: F401  (registers the pallas backend)
     from repro.core.search_vec import ca_search_batch
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    rows, k1, m0, mo = 8, 2, 1024, 4096
+    if backend == "xla":
+        rows, k1, m0, mo = 1, 3, 8192, 131072
+    else:
+        rows, k1, m0, mo = 8, 2, 1024, 4096
     compiled = ca_search_batch.lower(
         shape(rows, m0), shape(rows, m0), shape(rows, m0),
         shape(rows, k1, mo), shape(rows, k1, mo), shape(rows), shape(rows, k1),
-        semantics="elca", backend=backend,
+        semantics=semantics, backend=backend,
     ).compile()
     if backend == "pallas":
         _assert_kernel(compiled)
     else:
+        text = compiled.as_text()
         # a sort costs seconds of TPU compile per bucket: the CA set is
         # compacted by prefix sum instead
-        assert " sort(" not in compiled.as_text()
+        assert " sort(" not in text
+        # list positions come from a block compare, not a binary search's
+        # loop of scalar gathers
+        assert " while(" not in text
+        # the compares feed their sums in fusions: a materialised
+        # lists x queries x heads table (3 x 8192 x 1024) would take 25 MB
+        # as bool and 100 MB as int32; the program needs under 1 MB
+        assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
