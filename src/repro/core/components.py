@@ -19,7 +19,6 @@ NOTE on the paper's figures: Fig. 4/5 key the RCPM by the *anchor* node
 root's instance id (ids 5/12 in the example).  Both produce identical final
 results; we implement the prose variant because it supports multiple nested
 RCs under one parent node (the anchor variant cannot key them apart).
-DESIGN.md records this choice.
 """
 from __future__ import annotations
 
@@ -94,8 +93,8 @@ def split_components(tree: XMLTree, dag: DagInfo) -> RedundancyComponents:
     root_rc = new_rc(0)
     # stack holds (canonical_node, rc). Canonical nodes' original children are
     # traversed; a child occurrence inside an RC is always canonical itself
-    # (proved in DESIGN.md §2: equal occ => 1:1 instances => first occurrence
-    # lies under the parent's first occurrence).
+    # (equal occ => 1:1 instances => the first occurrence lies under the
+    # parent's first occurrence).
     stack: list[tuple[int, int]] = [(0, root_rc)]
     rc_of_node[0] = root_rc
     while stack:
@@ -157,11 +156,17 @@ class IDClusterIndex:
         self.containment = containment or build_containment(tree)
         self.dag = dag or compress(tree)
         self.rcs = rcs or split_components(tree, self.dag)
+        # probe_dummies casts node ids to the table's dtype: it must hold them
+        id_max = np.iinfo(self.rcs.dummy_ids.dtype).max
+        if tree.num_nodes - 1 > id_max:
+            raise ValueError(
+                f"{tree.num_nodes} nodes overflow the RCPM's "
+                f"{self.rcs.dummy_ids.dtype} ids"
+            )
         # node id -> owning RC for *list membership*:
         #   members: rc_of_node; dummies: dummy_parent_rc (a node can be both
         #   a member of its own RC and a dummy inside a parent RC).
         self._member_rc = self.rcs.rc_of_node
-        self._dummy_pos = {int(d): i for i, d in enumerate(self.rcs.dummy_ids)}
         self._cache: dict[tuple[int, int], IDList] = {}
 
     # ------------------------------------------------------------------ #
@@ -175,16 +180,30 @@ class IDClusterIndex:
     def rcpm_lookup(self, node_id: int) -> RCPMEntry | None:
         return self.rcs.rcpm_lookup(node_id)
 
-    def nested_rcs(self, rc: int, ids: np.ndarray) -> list[int]:
-        """RCs that the dummies among ``rc``'s result ``ids`` point to, once
-        each in first-seen order: one vectorized RCPM probe for the whole
-        result set (a per-id probe costs a host call per result)."""
+    def probe_dummies(
+        self, rc: int, ids: np.ndarray
+    ) -> tuple[np.ndarray, np.ndarray]:
+        """One vectorized RCPM probe of ``rc``'s result ``ids``: their
+        positions in the dummy table (clipped into it) and the mask of the
+        ids that are dummies, ``rc``'s own root excluded.
+
+        The keys are cast to the table's dtype, which ``__init__`` checked
+        holds every node id: searching int64 keys would make NumPy copy the
+        whole table to int64 first, a cost of the corpus, not the answer."""
         dummy_ids = self.rcs.dummy_ids
         if not dummy_ids.size or not ids.size:
-            return []
-        pos = np.clip(np.searchsorted(dummy_ids, ids), 0, dummy_ids.size - 1)
-        hit = (dummy_ids[pos] == ids) & (ids != self.rc_root_id(rc))
-        nested = self.rcs.dummy_nested_rc[pos[hit]].tolist()
+            return np.zeros(ids.shape, np.intp), np.zeros(ids.shape, bool)
+        keys = ids.astype(dummy_ids.dtype, copy=False)
+        pos = np.clip(np.searchsorted(dummy_ids, keys), 0, dummy_ids.size - 1)
+        is_dummy = (dummy_ids[pos] == keys) & (keys != self.rc_root_id(rc))
+        return pos, is_dummy
+
+    def nested_rcs(self, rc: int, ids: np.ndarray) -> list[int]:
+        """RCs that the dummies among ``rc``'s result ``ids`` point to, once
+        each in first-seen order (a per-id probe costs a host call per
+        result)."""
+        pos, is_dummy = self.probe_dummies(rc, ids)
+        nested = self.rcs.dummy_nested_rc[pos[is_dummy]].tolist()
         return list(dict.fromkeys(nested))
 
     def idlist(self, rc: int, kw: int) -> IDList:

@@ -118,10 +118,9 @@ def dag_search_vec_multi(
 
     All (query, rc) work items of a round that share a keyword count are
     packed into one launch through the PlanCache — the cross-query batching
-    that amortizes dispatch overhead (EXPERIMENTS.md §Perf, search iteration
-    3) — and the cache's R-bucketing keeps the jit executable set shared
-    across *calls*, not just rounds.  Memoisation is per query (different
-    keyword sets ⇒ different RC results).
+    that amortizes dispatch overhead — and the cache's R-bucketing keeps the
+    jit executable set shared across *calls*, not just rounds.  Memoisation
+    is per query (different keyword sets ⇒ different RC results).
 
     ``backend`` picks the device path *inside* the shared batch search:
     "xla" (or "pallas" once :mod:`repro.kernels.ops` has registered its
@@ -234,25 +233,18 @@ def _splice(
 ) -> np.ndarray:
     """Resolve dummy results through the RCPM (host-side materialization).
 
-    The RCPM probe is vectorized per RC (one searchsorted over all results);
+    The RCPM probe is vectorized per RC (:meth:`IDClusterIndex.probe_dummies`);
     only actual dummies loop.  ``counts`` (traced splices only) gets the
     number of dummies resolved under ``"dummies"``."""
     resolved: dict[int, np.ndarray] = {}
     rcs = index.rcs
-    dummy_ids = rcs.dummy_ids
 
     def resolve(rc: int) -> np.ndarray:
         got = resolved.get(rc)
         if got is not None:
             return got
-        root = index.rc_root_id(rc)
         res = memo.get(rc, np.zeros(0, dtype=np.int64))
-        if dummy_ids.size and res.size:
-            pos = np.searchsorted(dummy_ids, res)
-            pos_c = np.clip(pos, 0, dummy_ids.size - 1)
-            is_dummy = (dummy_ids[pos_c] == res) & (res != root)
-        else:
-            is_dummy = np.zeros(res.shape, dtype=bool)
+        pos_c, is_dummy = index.probe_dummies(rc, res)
         if not is_dummy.any():
             resolved[rc] = res
             return res
