@@ -1,7 +1,6 @@
 """§Perf hillclimb for the paper's own technique (measured wall time).
 
-Unlike the LM cells (dry-run proxies), the search engine RUNS here, so each
-iteration is a real measurement.  Knobs:
+Each iteration times the search engine itself (host wall clock).  Knobs:
   * backend: scalar (paper-faithful) vs vectorized XLA vs Pallas kernels;
   * DAG frontier execution: per-RC calls vs batched rounds;
   * bucket sizing: pow2 padding granularity (jit cache hits vs padding waste).

@@ -92,7 +92,6 @@ def main(argv=None) -> int:
         bench_db_size,
         bench_index_size,
         bench_kernels,
-        bench_prefix_dag,
         bench_query_length,
         bench_search_hillclimb,
         bench_service,
@@ -110,7 +109,6 @@ def main(argv=None) -> int:
         ("beyond-paper: vectorized backends", bench_vectorized),
         ("beyond-paper: per-kernel microbench", bench_kernels),
         ("beyond-paper: search perf hillclimb", bench_search_hillclimb),
-        ("beyond-paper: prefix-DAG serving dedup", bench_prefix_dag),
         ("beyond-paper: query service throughput", bench_service),
         ("beyond-paper: cluster scatter-gather throughput", bench_cluster),
     ]

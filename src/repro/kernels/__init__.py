@@ -4,7 +4,6 @@ interpret=True against the pure-jnp oracles in ref.py):
   intersect          sorted-set membership (the CA-intersection inner loop)
   searchsorted       count-based blocked binary search
   elca_segsum        ELCA child-NDesc aggregation as a masked mat-sum
-  decode_attention   fused GQA flash-decode over the KV cache
 
 ops.py hosts the jit-ready wrappers (window bookkeeping, padding) and the
 kernel-backed query path used by engine backend="pallas".

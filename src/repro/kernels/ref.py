@@ -29,22 +29,3 @@ def elca_segsum_ref(
     """child_sum[k, i] = sum of nd[k, j] where par[j] == ca[i] (dense oracle)."""
     eq = par_padded[None, :] == ca_padded[:, None]  # [MI, MJ]
     return jnp.einsum("ij,kj->ki", eq.astype(jnp.int32), nd_padded)
-
-
-def decode_attention_ref(
-    q: jax.Array,  # [B, H, hd]
-    k: jax.Array,  # [B, T, Hk, hd]
-    v: jax.Array,  # [B, T, Hk, hd]
-    cache_len: jax.Array,  # [B] int32
-) -> jax.Array:
-    """Plain masked softmax attention for one token (decode oracle)."""
-    b, h, hd = q.shape
-    hk = k.shape[2]
-    n_rep = h // hk
-    kf = jnp.repeat(k, n_rep, axis=2).astype(jnp.float32)  # [B,T,H,hd]
-    vf = jnp.repeat(v, n_rep, axis=2).astype(jnp.float32)
-    logits = jnp.einsum("bhd,bthd->bht", q.astype(jnp.float32), kf) / (hd**0.5)
-    mask = jnp.arange(k.shape[1])[None, None, :] < cache_len[:, None, None]
-    logits = jnp.where(mask, logits, -1e30)
-    probs = jax.nn.softmax(logits, axis=-1)
-    return jnp.einsum("bht,bthd->bhd", probs, vf).astype(q.dtype)

@@ -1,51 +1,17 @@
 """Property tests: every engine configuration equals the brute-force oracle.
 
-Random trees are generated with deliberate redundancy (shared NodeSpec
-subtrees) so DAG compression, dummy nodes, nested RCs, and offset splicing
-are all exercised; hypothesis drives sizes/seeds/queries.
+Random trees (``random_trees.random_tree``) carry deliberate redundancy
+(shared NodeSpec subtrees) so DAG compression, dummy nodes, nested RCs, and
+offset splicing are all exercised; hypothesis drives sizes/seeds/queries.
 """
 import numpy as np
 import pytest
 
 hypothesis = pytest.importorskip("hypothesis")  # optional test dep (see pyproject [test])
 from hypothesis import given, settings, strategies as st
+from random_trees import WORDS, random_tree
 
-from repro.core import KeywordSearchEngine, NodeSpec, build_tree
-from repro.core import brute
-
-WORDS = ["usa", "english", "vinyl", "rock", "jazz", "rpm", "red", "blue"]
-
-
-def random_tree(seed: int, n_target: int) -> tuple:
-    rng = np.random.default_rng(seed)
-
-    def words() -> str:
-        k = int(rng.integers(0, 3))
-        return " ".join(rng.choice(WORDS, size=k, replace=True)) if k else ""
-
-    pool: list[NodeSpec] = []
-    count = [0]
-
-    def make(depth: int) -> NodeSpec:
-        count[0] += 1
-        # reuse an existing subtree (creates redundancy / nested RCs)
-        if pool and rng.random() < 0.3:
-            return pool[int(rng.integers(0, len(pool)))]
-        n_children = 0
-        if depth < 5 and count[0] < n_target:
-            n_children = int(rng.integers(0, 4))
-        node = NodeSpec(
-            label=f"tag{int(rng.integers(0, 4))}",
-            text=words(),
-            children=[make(depth + 1) for _ in range(n_children)],
-        )
-        if rng.random() < 0.4:
-            pool.append(node)
-        return node
-
-    root = NodeSpec("root", children=[make(1) for _ in range(3)])
-    tree = build_tree(root)
-    return tree
+from repro.core import KeywordSearchEngine, brute
 
 
 @st.composite
